@@ -1,11 +1,9 @@
 """Experiment 2 (Fig. 6): domain-parallel scaling.
 
 The paper splits each relation into contiguous blocks per thread. Here the
-same freedom is exercised two ways:
-  * ``partitioned_figaro_qr`` — fact-table row partitions, independent FiGaRo
-    per partition, TSQR combine (the paper's domain parallelism);
-  * device-sharded TSQR post-processing over N host devices (subprocess,
-    since the XLA device count is fixed at startup).
+same freedom is exercised by ``partitioned_figaro_qr``: fact-table row
+partitions, independent FiGaRo per partition, TSQR combine (the paper's
+domain parallelism).
 
 This container exposes ONE physical core, so wall-clock speedup is not
 observable; the benchmark reports the *load balance* (max rows per worker,
@@ -14,10 +12,6 @@ reference, and asserts result invariance across partition counts.
 """
 
 from __future__ import annotations
-
-import os
-import subprocess
-import sys
 
 import jax.numpy as jnp
 import numpy as np

@@ -19,6 +19,10 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 

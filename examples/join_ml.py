@@ -17,6 +17,10 @@ Run:  PYTHONPATH=src python examples/join_ml.py
 import jax
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 import numpy as np
 
 from repro import figaro

@@ -33,6 +33,10 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 import jax.numpy as jnp
 import numpy as np
 

@@ -156,6 +156,9 @@ def main() -> None:
                     help="serve FiGaRo factorizations over the data mesh "
                          "instead of the LM demo")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.figaro:
         figaro_demo(args)
     else:

@@ -1,98 +1,39 @@
-"""Version-compat shims for the pinned JAX.
+"""The one import point for the version-sensitive `jax.sharding` surface.
 
-The codebase targets the current jax.sharding surface (``AxisType``,
-``jax.make_mesh(..., axis_types=...)``, top-level ``jax.shard_map``,
-keyword-style ``AbstractMesh``); the container pins an older JAX where those
-spellings differ or don't exist.  Everything version-sensitive is funneled
-through this module so the rest of the tree imports one stable API:
+FIG001 (`analysis/rules/compat_pin.py`) keeps the raw spellings of these
+symbols inside this module, so a future JAX rename touches one file. The
+module is written against the installed JAX (0.9) and carries no branch for
+any other version:
 
-  AxisType             the real enum when available, else a stand-in Enum
-  make_mesh            jax.make_mesh, dropping ``axis_types`` when unsupported
-  make_abstract_mesh   AbstractMesh under both calling conventions
-  shard_map            jax.shard_map or jax.experimental.shard_map.shard_map
+  AxisType             `jax.sharding.AxisType`
+  make_mesh            `jax.make_mesh`
+  make_abstract_mesh   `jax.sharding.AbstractMesh(shapes, names, axis_types=)`
+  shard_map            `jax.shard_map`
+  axis_size            `jax.lax.axis_size`
 """
 
 from __future__ import annotations
 
-import enum
 from typing import Sequence
 
 import jax
+from jax import shard_map
+from jax.lax import axis_size
+from jax.sharding import AbstractMesh, AxisType
 
 __all__ = ["AxisType", "make_mesh", "make_abstract_mesh", "shard_map",
            "axis_size"]
 
 
-try:  # jax >= 0.5-ish
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    _HAS_AXIS_TYPE = True
-except ImportError:  # pinned jax: meshes are implicitly fully-Auto
-    _HAS_AXIS_TYPE = False
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stand-in for jax.sharding.AxisType (older JAX is all-Auto)."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates JAX versions without ``axis_types``."""
-    kwargs = {} if devices is None else {"devices": devices}
-    if _HAS_AXIS_TYPE and axis_types is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=axis_types, **kwargs)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    """`jax.make_mesh` with keyword-only ``axis_types`` / ``devices``."""
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
+                         devices=devices)
 
 
 def make_abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
                        *, axis_types=None):
-    """AbstractMesh across the constructor change: new JAX takes
-    ``(shapes, names, axis_types=...)``, the pinned one ``(((name, size), ...))``."""
-    from jax.sharding import AbstractMesh
-    try:
-        if axis_types is not None:
-            return AbstractMesh(tuple(axis_shapes), tuple(axis_names),
-                                axis_types=axis_types)
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
-
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """`shard_map` with the replication-check kwarg normalized across JAX
-    versions: pre-0.7 spells it ``check_rep``, newer JAX renamed it to
-    ``check_vma``. Callers may pass either; the unsupported spelling is
-    translated rather than exploding on the pinned version."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-    except TypeError:
-        if "check_rep" in kwargs:
-            kwargs["check_vma"] = kwargs.pop("check_rep")
-        elif "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        else:
-            raise
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` with a psum(1) fallback for JAX versions
-    predating it (inside shard_map/pmap collectives only)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    import jax.numpy as jnp
-    return jax.lax.psum(jnp.ones((), jnp.int32), axis_name)
+    """A device-free `AbstractMesh` (for sharding-rule tests)."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names),
+                        axis_types=axis_types)

@@ -291,13 +291,13 @@ class FigaroEngine:
                 # leading request-batch axis of every data leaf is split over
                 # ``mesh[axis]``; every output leaf has a leading batch axis.
                 body = lambda p, d: impl(p, d, **options)
-                # check_rep=False: pallas_call (the fused node kernel) has no
+                # check_vma=False: pallas_call (the fused node kernel) has no
                 # replication rule, and nothing here relies on the check —
                 # the plan is replicated in, all outputs are P(axis)-sharded.
                 mapped = shard_map(body, mesh=mesh,
                                    in_specs=(P(), P(axis)),
                                    out_specs=P(axis),
-                                   check_rep=False)
+                                   check_vma=False)
                 return mapped(plan, data)
 
         # wraps() keeps impl's signature visible so static_argnames resolve,

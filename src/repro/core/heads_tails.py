@@ -79,25 +79,41 @@ def head_tail(a: jnp.ndarray, v: jnp.ndarray | None = None):
 # ---------------------------------------------------------------------------
 
 
+def _shift_down(x: jnp.ndarray, off: int, fill) -> jnp.ndarray:
+    """Rows shifted down by ``off`` (row r reads r-off), ``fill`` on top."""
+    pad = ((off, 0),) + ((0, 0),) * (x.ndim - 1)
+    return jnp.pad(x[:x.shape[0] - off], pad, constant_values=fill)
+
+
 def segmented_cumsum(x: jnp.ndarray, first_flag: jnp.ndarray) -> jnp.ndarray:
     """Inclusive cumsum that restarts wherever ``first_flag`` is True.
 
-    Implemented with an associative scan (no subtract-the-base trick), so long
-    arrays do not suffer cross-segment cancellation — this mirrors what the
-    Pallas kernel does natively on TPU.
+    A Hillis–Steele ladder, the same scan the Pallas kernels run per row
+    block: log₂(m) steps, step k adding the partial sum 2^k rows up unless a
+    segment starts in between. It adds only values of the same segment (no
+    subtract-the-base trick), so long arrays do not suffer cross-segment
+    cancellation. It uses contiguous shifts only: `jax.lax.associative_scan`
+    takes strided slices, for which the TPU compiler's time grows with the
+    length (minutes for 2^20 rows on a v5e); the ladder compiles in seconds
+    at any length.
+
+    The ladder starts behind an optimization barrier, so XLA cannot fold
+    or fuse it into its producers: a plan whose weights are constant ones
+    and a capacity plan whose weights are a live-row mask of ones then run
+    the same arithmetic and give bit-identical results.
     """
     flags = first_flag
     if x.ndim == 2:
         flags = first_flag[:, None]
     flags = jnp.broadcast_to(flags, x.shape)
-
-    def combine(a, b):
-        fa, xa = a
-        fb, xb = b
-        return fa | fb, xb + jnp.where(fb, jnp.zeros_like(xa), xa)
-
-    _, out = jax.lax.associative_scan(combine, (flags, x), axis=0)
-    return out
+    x, flags = jax.lax.optimization_barrier((x, flags))
+    off = 1
+    while off < x.shape[0]:
+        x = x + jnp.where(flags, jnp.zeros((), x.dtype),
+                          _shift_down(x, off, 0))
+        flags = flags | _shift_down(flags, off, False)
+        off *= 2
+    return x
 
 
 def segmented_head_tail(

@@ -15,6 +15,12 @@ source of truth:
                              interpret-off-accelerator. The override is how
                              tests force the interpreter on an accelerator
                              (numerics triage) or assert compiled lowering.
+  * `compiled_dtype_check` — the refusal every kernel makes before a compiled
+                             lowering of float64 data, which the TPU's Pallas
+                             compiler cannot build. The refusal names the
+                             switch (``use_kernel``) and the dtype instead of
+                             failing deep inside XLA, and nothing swaps in
+                             the XLA path behind the caller's back.
 """
 
 from __future__ import annotations
@@ -22,8 +28,10 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
-__all__ = ["backend", "on_accelerator", "on_tpu", "resolve_interpret"]
+__all__ = ["backend", "on_accelerator", "on_tpu", "resolve_interpret",
+           "compiled_dtype_check"]
 
 _ACCELERATORS = ("tpu", "gpu")
 
@@ -53,3 +61,13 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     if interpret is not None:
         return bool(interpret)
     return not on_accelerator()
+
+
+def compiled_dtype_check(dtype, kernel: str) -> None:
+    """Raise `ValueError` for float64 data on a compiled kernel path."""
+    if jnp.dtype(dtype) == jnp.float64:
+        raise ValueError(
+            f"{kernel}: use_kernel=True cannot run float64 data compiled "
+            f"(got dtype {jnp.dtype(dtype).name}); the TPU's Pallas compiler "
+            f"has no float64. Pass dtype=jnp.float32 with use_kernel=True, "
+            f"or use_kernel=False for float64.")

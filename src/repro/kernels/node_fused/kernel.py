@@ -21,9 +21,11 @@ TPU mapping follows `kernels/head_tail`: grid = (col_blocks, row_blocks) with
 rows innermost, so each column stripe walks row blocks sequentially and hands
 the running segment prefix forward through VMEM scratch; the in-block
 segmented scan is a Hillis–Steele ladder (log₂ bm vector steps on the VPU).
-Accumulation is f32 for ≤32-bit I/O and f64 for f64 I/O (f64 pipelines run in
-interpret mode on this container, where the wider carry is free; on TPU
-hardware the engine dispatches f32).
+Accumulation is f32 for ≤32-bit I/O and f64 for f64 I/O. The f64 path exists
+only in interpret mode: the TPU's Pallas compiler has no float64, so a
+compiled (``interpret=False``) call with float64 data raises `ValueError`
+before lowering — run the kernel path (``use_kernel=True``) at float32, or
+take the XLA path for float64.
 
 Grid/block sizing comes from the `AUTOTUNE` table, keyed by
 ``(backend, itemsize, width bound)``: narrow nodes take taller row blocks
@@ -45,6 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import _platform
+from repro.kernels._platform import compiled_dtype_check
 
 # (backend, itemsize, width bound) -> (block_rows, block_cols). Buckets are
 # checked in order; `None` is the catch-all bound each (backend, itemsize)
@@ -142,6 +145,8 @@ def node_fused_kernel(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (emitted [m, n], s_incl [m, n]) — see module docstring."""
     m, n = data.shape
+    if not interpret:
+        compiled_dtype_check(data.dtype, "node_fused_kernel")
     if block_rows is None or block_cols is None:
         tuned = choose_blocks(n, data.dtype)
         block_rows = block_rows or tuned[0]
@@ -165,7 +170,9 @@ def node_fused_kernel(
 
     grid = (np_ // bn, mp // bm)
     row_spec = pl.BlockSpec((bm, bn), lambda j, i: (i, j))
-    vec_spec = pl.BlockSpec((bm, 1), lambda j, i: (i, 0))
+    # An int32 block index: under jax_enable_x64 a bare 0 is int64, which
+    # Mosaic refuses to return from the index map.
+    vec_spec = pl.BlockSpec((bm, 1), lambda j, i: (i, jnp.int32(0)))
     emitted, s_incl = pl.pallas_call(
         functools.partial(_node_fused_body, block_rows=bm, acc_dtype=acc_dtype),
         grid=grid,

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels._platform import compiled_dtype_check
+
 
 def _panel_kernel(a_ref, v_ref, beta_ref, r_ref, *, m: int, nb: int):
     # Accumulate in the I/O precision: f64 panels (the x64 post-processing
@@ -42,7 +44,8 @@ def _panel_kernel(a_ref, v_ref, beta_ref, r_ref, *, m: int, nb: int):
         sigma = jnp.sqrt(sigma2)
         at_k = (rows == k).astype(acc)
         xk = jnp.sum(x * at_k)
-        sgn = jnp.where(xk >= 0, 1.0, -1.0)
+        one = jnp.ones((), acc)  # a bare 1.0 would select in f64 under x64
+        sgn = jnp.where(xk >= 0, one, -one)
         alpha = -sgn * sigma
         v = x - alpha * at_k
         vk = jnp.sum(v * at_k)
@@ -58,7 +61,11 @@ def _panel_kernel(a_ref, v_ref, beta_ref, r_ref, *, m: int, nb: int):
 
     vs0 = jnp.zeros((m, nb), acc)
     betas0 = jnp.zeros((1, nb), acc)
-    a, vs, betas = jax.lax.fori_loop(0, min(m, nb), step, (a, vs0, betas0))
+    # int32 bounds: under jax_enable_x64 Python-int bounds make the loop
+    # index int64, which Mosaic cannot lower (the bool->float mask casts
+    # recurse without end).
+    a, vs, betas = jax.lax.fori_loop(jnp.int32(0), jnp.int32(min(m, nb)),
+                                     step, (a, vs0, betas0))
 
     v_ref[...] = vs.astype(v_ref.dtype)
     beta_ref[...] = betas.astype(beta_ref.dtype)
@@ -74,11 +81,18 @@ def panel_qr_kernel(a: jnp.ndarray, *, interpret: bool = False):
     Returns (V [m, nb] unit-diagonal reflectors, beta [nb], R_panel [m, nb]).
     VMEM budget: 4 copies of the panel at the accumulation dtype (f64 for
     f64 panels, f32 otherwise) — keep m·nb ≲ 512·128 (f32) / 512·64 (f64).
+    f64 panels run only with ``interpret=True``; a compiled call raises
+    `ValueError` (the TPU's Pallas compiler has no float64).
     """
     m, nb = a.shape
+    if not interpret:
+        compiled_dtype_check(a.dtype, "panel_qr_kernel")
     kern = functools.partial(_panel_kernel, m=m, nb=nb)
-    spec = pl.BlockSpec((m, nb), lambda: (0, 0))
-    bspec = pl.BlockSpec((1, nb), lambda: (0, 0))
+    # int32 block indices (a bare 0 is int64 under jax_enable_x64, which
+    # Mosaic refuses to return from the index map).
+    origin = lambda: (jnp.int32(0), jnp.int32(0))
+    spec = pl.BlockSpec((m, nb), origin)
+    bspec = pl.BlockSpec((1, nb), origin)
     v, beta, r = pl.pallas_call(
         kern,
         grid=(),

@@ -21,7 +21,10 @@ serving layer into a small pipeline:
     submission order. Exceptions propagate per-request: a request that fails
     validation resolves only its own future, and if a coalesced dispatch
     fails at run time, each batched request is re-dispatched alone so one
-    poisoned request cannot fail its batchmates;
+    poisoned request cannot fail its batchmates. Such a fallback runs a
+    different program from the one asked for, so it is never silent: every
+    isolated re-dispatch is counted in ``stats()``, the batch's exception is
+    kept there, and a warning is logged;
   * ``append(node, rows)`` joins the same stream — it drains in-flight work,
     then refreshes the shared `plan_cache.PlanHolder` (zero retraces while
     live sizes stay within capacity), so the owning `JoinDataset`'s plan and
@@ -35,6 +38,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import functools
+import logging
 import queue
 import threading
 import weakref
@@ -50,6 +54,8 @@ from repro.sanitizer.threads import san_thread
 
 __all__ = ["SERVE_KINDS", "validate_serve_kind", "FigaroFuture",
            "AsyncFigaroServer"]
+
+_log = logging.getLogger(__name__)
 
 #: The serving kinds every serving surface supports (`make_figaro_server`,
 #: `Session.serve`, `JoinDataset.serve`) — validated eagerly, in one place.
@@ -199,7 +205,8 @@ def _complete_loop(server_ref, out_q):
         del server
 
 
-@shared_state({"_outstanding": "_cond", "_closed": "_close_lock",
+@shared_state({"_outstanding": "_cond", "_isolated": "_cond",
+               "_batch_error": "_cond", "_closed": "_close_lock",
                "_threads": "_thread_lock"})
 class AsyncFigaroServer:
     """Pipelined micro-batching serving endpoint for one join structure.
@@ -219,6 +226,10 @@ class AsyncFigaroServer:
         data_rows)`` to relation ``node`` through the shared `PlanHolder` —
         the owning `JoinDataset` (and every sibling server) sees the same
         refreshed plan. True = still within capacity (zero retraces).
+    ``stats()``
+        ``isolated_redispatches`` (requests re-dispatched alone after their
+        coalesced batch failed; 0 on a healthy path) and ``batch_error``
+        (the last failed batch's exception, or None).
     ``flush()`` / ``close()`` / ``pause()`` / ``resume()``
         Drain outstanding requests; shut the worker threads down; hold /
         release the coalescer (pause + submit + resume dispatches one
@@ -259,6 +270,8 @@ class AsyncFigaroServer:
         self._close_lock = san_lock("server._close_lock")  # closed vs enqueue
         self._thread_lock = san_lock("server._thread_lock")
         self._outstanding = 0
+        self._isolated = 0
+        self._batch_error: BaseException | None = None
         self._closed = False
         self._threads: list[threading.Thread] | None = None
         self._finalizer = weakref.finalize(self, self._in_q.put, _SHUTDOWN)
@@ -443,6 +456,13 @@ class AsyncFigaroServer:
         elif len(live) > 1:
             # A coalesced dispatch failed: isolate the poisoned request(s) by
             # re-dispatching each request alone — batchmates still succeed.
+            # Counted and logged: a batch that cannot compile or run out of
+            # device memory must not pass for a healthy one.
+            _log.warning("coalesced batch of %d requests failed (%r); "
+                         "re-dispatching each request alone", len(live), err)
+            with self._cond:
+                self._isolated += len(live)
+                self._batch_error = err
             for it in live:
                 try:
                     o = self._dispatch_fn(it.plan, it.arrays,
@@ -462,6 +482,12 @@ class AsyncFigaroServer:
                 it.future._resolve(error=errors.get(id(it), err))
             self._done_one()
         self._depth_sem.release()
+
+    def stats(self) -> dict:
+        """Serving counters (see the class docstring)."""
+        with self._cond:
+            return {"isolated_redispatches": self._isolated,
+                    "batch_error": self._batch_error}
 
     def _fail_item(self, item, error: BaseException) -> None:
         if isinstance(item, _Request) and not item.future.done():

@@ -5,87 +5,13 @@ double precision); model tests cast explicitly via cfg dtypes and are
 unaffected. The XLA device-count flag is NEVER set here — distributed tests
 spawn subprocesses (see test_distributed.py / test_dryrun.py) so smoke tests
 and benchmarks keep seeing the single real device.
-
-When the real ``hypothesis`` package is absent (the container doesn't ship
-it), a minimal deterministic stand-in is installed into ``sys.modules`` before
-test modules import: ``@given`` runs each property test over ``max_examples``
-pseudo-random draws from a fixed seed. Same API subset, reproducible draws,
-no external dependency.
 """
-
-import functools
-import inspect
-import sys
-import types
 
 import jax
 import numpy as np
 import pytest
 
 jax.config.update("jax_enable_x64", True)
-
-
-def _install_hypothesis_stub() -> None:
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    def integers(min_value, max_value):
-        return _Strategy(
-            lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-    def booleans():
-        return _Strategy(lambda rng: bool(rng.integers(0, 2)))
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda rng: elements[int(rng.integers(len(elements)))])
-
-    def given(**strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_stub_max_examples", 20)
-                rng = np.random.default_rng(0)
-                for _ in range(n):
-                    draws = {k: s.draw(rng) for k, s in strategies.items()}
-                    fn(*args, **{**kwargs, **draws})
-            # pytest must not see the strategy-bound params (it would resolve
-            # them as fixtures) nor unwrap back to the original signature.
-            del wrapper.__wrapped__
-            sig = inspect.signature(fn)
-            wrapper.__signature__ = sig.replace(parameters=[
-                p for name, p in sig.parameters.items()
-                if name not in strategies])
-            return wrapper
-        return deco
-
-    def settings(max_examples=None, deadline=None, **_ignored):
-        def deco(fn):
-            if max_examples is not None:
-                fn._stub_max_examples = max_examples
-            return fn
-        return deco
-
-    strat = types.ModuleType("hypothesis.strategies")
-    strat.integers = integers
-    strat.booleans = booleans
-    strat.sampled_from = sampled_from
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = strat
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strat
-
-
-_install_hypothesis_stub()
 
 
 @pytest.fixture

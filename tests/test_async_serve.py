@@ -231,6 +231,43 @@ def test_poisoned_dispatch_does_not_fail_coalesced_batchmates(rng):
     server.close()
 
 
+def test_isolated_redispatches_are_counted(rng):
+    """A clean coalesced batch leaves the isolation counter at 0; a poisoned
+    one counts each request re-dispatched alone and keeps the batch's
+    exception in ``stats()``."""
+    plan = build_plan(_star_tree())
+    server = make_figaro_server(plan, kind="qr", dtype=jnp.float64,
+                                engine=FigaroEngine(donate_data=False))
+    real = server._dispatch_fn
+
+    def flaky(plan_, batch, cap):
+        if np.shape(batch[0])[0] > 1:
+            raise RuntimeError("coalesced batch failed")
+        return real(plan_, batch, cap)
+
+    reqs = _requests(plan, rng, 3)
+    server.pause()
+    futures = [server.submit(r) for r in reqs]
+    server.resume()
+    for f in futures:
+        f.result(timeout=60)
+    assert server.stats() == {"isolated_redispatches": 0,
+                              "batch_error": None}
+
+    server._dispatch_fn = flaky
+    server.pause()
+    futures = [server.submit(r) for r in reqs]
+    server.resume()
+    for f in futures:  # each request still answers, alone
+        assert np.asarray(f.result(timeout=60)).shape \
+            == (plan.num_cols, plan.num_cols)
+    stats = server.stats()
+    assert stats["isolated_redispatches"] == 3
+    assert isinstance(stats["batch_error"], RuntimeError)
+    assert "coalesced batch failed" in str(stats["batch_error"])
+    server.close()
+
+
 # -- streaming submit/append with zero retraces -------------------------------
 
 

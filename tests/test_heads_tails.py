@@ -136,6 +136,23 @@ def test_segmented_cumsum_restarts(rng):
     np.testing.assert_allclose(out, expect, rtol=1e-12)
 
 
+@pytest.mark.parametrize("m, width", [(37, None), (40, 3), (64, 1)])
+def test_segmented_cumsum_ladder_matches_loop(rng, m, width):
+    """The Hillis–Steele ladder at lengths that are and are not powers of
+    two, with segments spanning several ladder strides."""
+    shape = (m,) if width is None else (m, width)
+    x = rng.normal(size=shape)
+    first = rng.uniform(size=m) < 0.1
+    first[0] = True
+    out = np.asarray(segmented_cumsum(jnp.array(x), jnp.array(first)))
+    expect = np.empty(shape)
+    acc = np.zeros(shape[1:])
+    for i in range(m):
+        acc = x[i] if first[i] else acc + x[i]
+        expect[i] = acc
+    np.testing.assert_allclose(out, expect, rtol=1e-12, atol=1e-12)
+
+
 def test_segmented_head_tail_matches_per_segment(rng):
     sizes = [3, 1, 5, 2]
     data = _rand(rng, sum(sizes), 4)

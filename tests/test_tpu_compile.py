@@ -1,0 +1,106 @@
+"""Ahead-of-time compiles of the main path for a described TPU v5e chip.
+
+The TPU compiler is installed alongside JAX, so these tests compile for a
+chip that is described, not attached: they catch what the interpret-mode
+kernel tests cannot (Mosaic lowering limits, VMEM budgets, int64 leaking
+into a kernel under ``jax_enable_x64``) at no chip time. Nothing here runs a
+program, so nothing here says anything about results or times.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports every test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.engine import FigaroEngine
+from repro.core.plan_cache import build_capacity_plan
+from repro.data.relational import yelp_like
+from repro.kernels import _platform
+from repro.kernels.node_fused.kernel import node_fused_kernel
+from repro.kernels.panel_qr.kernel import panel_qr_kernel
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # The TPU library logs under /tmp unless told otherwise; keep the test
+    # from writing outside the checkout.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Steer the platform policy to the chip's branch: compiled kernels and
+    the TPU block table, as `resolve_interpret(None)` picks on a TPU."""
+    monkeypatch.setattr(_platform, "backend", lambda: "tpu")
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# Node capacities of yelp_like(scale=1_000_000): Review [2^21, 1] and
+# User/Business [2^17, 3].
+@pytest.mark.parametrize("m, n", [(2**21, 1), (2**17, 3)])
+def test_node_fused_compiles_at_full_size(one_chip, m, n):
+    col = _spec((m, 1), jnp.float32, one_chip)
+    compiled = node_fused_kernel.lower(
+        _spec((m, n), jnp.float32, one_chip), col, col, col, col, col, col,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_panel_qr_compiles_at_tsqr_leaf(one_chip):
+    # The kernel path's TSQR leaf: leaf_rows=256 rows, one panel=32 wide.
+    compiled = panel_qr_kernel.lower(
+        _spec((256, 32), jnp.float32, one_chip), interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_qr_engine_program_compiles(one_chip, tpu_backend, use_kernel):
+    """The whole batched qr program the async server dispatches (B=4,
+    float32), for a small capacity plan."""
+    plan = build_capacity_plan(yelp_like())
+    as_spec = lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip)
+    plan_spec = jax.tree.map(as_spec, plan.without_data())
+    data_spec = tuple(_spec((4,) + np.shape(d), np.float64, one_chip)
+                      for d in plan.data)
+    program = FigaroEngine()._make_jitted("qr_batched", False, None, None,
+                                          None)
+    compiled = program.lower(
+        plan_spec, data_spec, dtype=np.dtype(np.float32), method="tsqr",
+        leaf_rows=256, panel=32, use_kernel=use_kernel,
+        assembly="padded").compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+
+
+@pytest.mark.parametrize("kernel", ["node_fused", "panel_qr"])
+def test_compiled_kernel_refuses_float64(kernel):
+    """float64 on a compiled kernel path fails with a ValueError naming the
+    switch and the dtype, before anything is lowered."""
+    if kernel == "node_fused":
+        col = np.ones((64, 1))
+        call = lambda: node_fused_kernel(np.ones((64, 2)), col, col, col,
+                                         col, col, col, interpret=False)
+    else:
+        call = lambda: panel_qr_kernel(np.ones((64, 8)), interpret=False)
+    with pytest.raises(ValueError, match=r"use_kernel.*float64"):
+        call()

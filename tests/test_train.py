@@ -115,8 +115,9 @@ def test_orthogonalize_produces_orthonormal_frame(rng):
 def test_compressed_psum_error_feedback(rng):
     """int8+EF all-reduce: single-step error bounded; residual carries it."""
     mesh = make_host_mesh()  # 1 device -> axis size 1: exactness check
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.compat import shard_map
 
     g = {"w": jnp.array(rng.normal(size=(8, 8)), jnp.float32)}
     r = init_residual(g)
