@@ -35,6 +35,12 @@ pytree argument:
     `plan_cache.refresh_plan` dispatch the same way without any per-call
     padding: an append that keeps the bucketed signature is retrace-free.
 
+The pipeline bodies run each phase under a `jax.named_scope` (see
+`core.figaro`): Algorithm 1's counts (``figaro.counts``, here also for the
+PCA means), Algorithm 2's passes, ``figaro.postprocess``, and the
+downstream svd / eigh / solve (``figaro.downstream``), so a device trace
+splits an executable's time by phase.
+
 Trace counts are tracked per pipeline kind (`trace_count`) so tests and
 benchmarks can assert cache hits instead of guessing. ``max_cached=`` bounds
 the per-kind executable cache with LRU eviction (`eviction_count`) — without
@@ -119,15 +125,17 @@ def _column_moments(plan: FigaroPlan, data, dtype):
     Σ_join A[:, Y_i] = Σ_r data_i[r] · Φ°_i(key(r)) — a per-node weighted sum.
     Node columns are preorder-contiguous, so the global vector is a concat.
     """
-    counts = compute_counts(plan, dtype=dtype)
-    parts = []
-    for sp, ix, d in zip(plan.spec.nodes, plan.index, data):
-        w = counts[sp.idx]["phi_circ"][jnp.asarray(ix.row_to_group)]
-        if ix.row_mask is not None:  # capacity plan: dead rows weigh nothing
-            w = w * jnp.asarray(ix.row_mask, dtype)
-        parts.append(w @ jnp.asarray(d, dtype))
-    sums = jnp.concatenate(parts)
-    total = counts[plan.spec.root]["full"].sum()
+    with jax.named_scope("figaro.counts"):
+        counts = compute_counts(plan, dtype=dtype)
+    with jax.named_scope("figaro.downstream"):
+        parts = []
+        for sp, ix, d in zip(plan.spec.nodes, plan.index, data):
+            w = counts[sp.idx]["phi_circ"][jnp.asarray(ix.row_to_group)]
+            if ix.row_mask is not None:  # capacity plan: dead rows weigh 0
+                w = w * jnp.asarray(ix.row_mask, dtype)
+            parts.append(w @ jnp.asarray(d, dtype))
+        sums = jnp.concatenate(parts)
+        total = counts[plan.spec.root]["full"].sum()
     return sums, total
 
 
@@ -467,7 +475,8 @@ class FigaroEngine:
         r = self._qr_one(plan, data, dtype=dtype, method=method,
                          leaf_rows=leaf_rows, panel=panel,
                          use_kernel=use_kernel, assembly=assembly)
-        _, s, vt = jnp.linalg.svd(r)
+        with jax.named_scope("figaro.downstream"):
+            _, s, vt = jnp.linalg.svd(r)
         return s, vt
 
     def _svd_impl(self, plan, data, *, dtype, method, leaf_rows, panel,
@@ -488,17 +497,18 @@ class FigaroEngine:
                          leaf_rows=leaf_rows, panel=panel,
                          use_kernel=use_kernel, assembly=assembly)
         sums, total = _column_moments(plan, data, dtype)
-        mean = sums / total
-        gram = r.T @ r
-        if center:
-            gram = gram - total * jnp.outer(mean, mean)
-        cov = gram / jnp.maximum(total - 1.0, 1.0)
-        evals, evecs = jnp.linalg.eigh(cov)  # ascending
-        # The centered-Gram subtraction can leave tiny negative eigenvalues
-        # (a variance); clamp before the top-k select so near-constant
-        # columns report 0, not -1e-17.
-        evals = jnp.maximum(evals, jnp.zeros((), evals.dtype))
-        order = jnp.argsort(-evals)[:k]
+        with jax.named_scope("figaro.downstream"):
+            mean = sums / total
+            gram = r.T @ r
+            if center:
+                gram = gram - total * jnp.outer(mean, mean)
+            cov = gram / jnp.maximum(total - 1.0, 1.0)
+            evals, evecs = jnp.linalg.eigh(cov)  # ascending
+            # The centered-Gram subtraction can leave tiny negative
+            # eigenvalues (a variance); clamp before the top-k select so
+            # near-constant columns report 0, not -1e-17.
+            evals = jnp.maximum(evals, jnp.zeros((), evals.dtype))
+            order = jnp.argsort(-evals)[:k]
         return PCAResult(components=evecs[:, order].T,
                          explained_variance=evals[order],
                          mean=mean, num_rows=total)
@@ -521,24 +531,26 @@ class FigaroEngine:
         r = self._qr_one(plan, data, dtype=dtype, method=method,
                          leaf_rows=leaf_rows, panel=panel,
                          use_kernel=use_kernel, assembly=assembly)
-        n = plan.spec.num_cols
-        feat = jnp.array([j for j in range(n) if j != label_col])
-        # Permute label last, re-triangularize the permuted R (cheap: N×N).
-        perm = jnp.concatenate([feat, jnp.array([label_col])])
-        rp = r[:, perm]
-        rr = jnp.linalg.qr(rp, mode="r")[:n]
-        r_ff = rr[: n - 1, : n - 1]
-        r_fl = rr[: n - 1, n - 1]
-        if ridge:
-            g = r_ff.T @ r_ff + ridge * jnp.eye(n - 1, dtype=dtype)
-            beta = jnp.linalg.solve(g, r_ff.T @ r_fl)
-            # The ridge solution does not zero the projected residual, so
-            # ‖Aβ − y‖ keeps both terms: ‖r_ff·β − r_fl‖² + rr[n−1,n−1]².
-            resid = jnp.sqrt(jnp.sum(jnp.square(r_ff @ beta - r_fl))
-                             + jnp.square(rr[n - 1, n - 1]))
-        else:
-            beta = jax.scipy.linalg.solve_triangular(r_ff, r_fl, lower=False)
-            resid = jnp.abs(rr[n - 1, n - 1])
+        with jax.named_scope("figaro.downstream"):
+            n = plan.spec.num_cols
+            feat = jnp.array([j for j in range(n) if j != label_col])
+            # Permute label last, re-triangularize the permuted R (cheap: N×N).
+            perm = jnp.concatenate([feat, jnp.array([label_col])])
+            rp = r[:, perm]
+            rr = jnp.linalg.qr(rp, mode="r")[:n]
+            r_ff = rr[: n - 1, : n - 1]
+            r_fl = rr[: n - 1, n - 1]
+            if ridge:
+                g = r_ff.T @ r_ff + ridge * jnp.eye(n - 1, dtype=dtype)
+                beta = jnp.linalg.solve(g, r_ff.T @ r_fl)
+                # The ridge solution does not zero the projected residual, so
+                # ‖Aβ − y‖ keeps both terms: ‖r_ff·β − r_fl‖² + rr[n−1,n−1]².
+                resid = jnp.sqrt(jnp.sum(jnp.square(r_ff @ beta - r_fl))
+                                 + jnp.square(rr[n - 1, n - 1]))
+            else:
+                beta = jax.scipy.linalg.solve_triangular(r_ff, r_fl,
+                                                         lower=False)
+                resid = jnp.abs(rr[n - 1, n - 1])
         return beta, resid
 
     def _least_squares_impl(self, plan, data, *, label_col, ridge, dtype,

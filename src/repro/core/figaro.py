@@ -50,6 +50,15 @@ nothing (weight 0, data zeroed) and the corresponding R₀ rows are exactly
 zero, so the same executable serves every live size up to capacity. The fused
 kernel keeps this contract: the mask rides in as the kernel's ``data_scale``
 so masked slab rows are exactly zero straight out of the kernel.
+
+Each phase of the traced body runs under a `jax.named_scope`, so a device op's
+name stack (the ``tf_op`` a profiler trace carries) says which phase it belongs
+to: ``figaro.counts`` (Algorithm 1), ``figaro.heads_tails`` (lines 11-16,
+with the node's name as a sub-scope), ``figaro.join_children`` (lines 17-26),
+``figaro.project`` (lines 27-34) and ``figaro.assemble``; the post-processing
+(``figaro.postprocess``) and the downstream reads (``figaro.downstream``) are
+scoped where they are traced. Scopes are metadata only: the compiled program
+is op for op the same.
 """
 
 from __future__ import annotations
@@ -152,7 +161,8 @@ def figaro_r0(
     if data is None:
         data = plan.data
     data = [jnp.asarray(d, dtype=dtype) for d in data]
-    counts = compute_counts(plan, dtype=dtype)
+    with jax.named_scope("figaro.counts"):
+        counts = compute_counts(plan, dtype=dtype)
 
     # Carried state per node (filled children-first); emitted slabs by node.
     carried_data: dict[int, jnp.ndarray] = {}
@@ -169,87 +179,98 @@ def figaro_r0(
         pos_in_group = jnp.asarray(ix.pos_in_group)
 
         # --- HEADS_AND_TAILS (lines 11-16) --------------------------------
-        # Capacity-padded plans weight the Givens sequences by the live-row
-        # mask: dead rows carry weight 0 (they neither move the prefix sums
-        # nor receive a tail) and their data is zeroed so the padded slab rows
-        # of R₀ come out identically zero. Dead rows are never segment starts
-        # (plan_cache appends them to the last live group), so every division
-        # inside the head/tail formulas stays well-posed.
-        mask = (jnp.asarray(ix.row_mask, dtype=dtype)
-                if ix.row_mask is not None else None)
-        weights = mask if mask is not None else jnp.ones((sp.m,), dtype=dtype)
-        phi_circ_row = cnt["phi_circ"][row_to_group]
-        if use_kernel:
-            # Fused pass: masking (data_scale), scan, tail, √Φ° scaling and
-            # start-row zeroing in one kernel; heads gathered from the
-            # segment-final inclusive sums.
-            last = jnp.asarray(ix.group_start) + jnp.asarray(ix.group_count) - 1
-            live = jnp.asarray(ix.group_count) > 0
-            slab, heads, _ = nf_ops.fused_node_pass(
-                x, weights, pos_in_group, jnp.sqrt(phi_circ_row), last, live,
-                data_scale=mask)
-            tail_slabs[idx] = slab
-        else:
-            if mask is not None:
-                x = x * mask[:, None]
-            heads, tails, _ = segmented_head_tail(
-                x, weights, row_to_group, pos_in_group, sp.K)
-            tail_slabs[idx] = tails * jnp.sqrt(phi_circ_row)[:, None]
+        with (jax.named_scope("figaro.heads_tails"),
+              jax.named_scope(sp.name)):
+            # Capacity-padded plans weight the Givens sequences by the
+            # live-row mask: dead rows carry weight 0 (they neither move the
+            # prefix sums nor receive a tail) and their data is zeroed so the
+            # padded slab rows of R₀ come out identically zero. Dead rows are
+            # never segment starts (plan_cache appends them to the last live
+            # group), so every division inside the head/tail formulas stays
+            # well-posed.
+            mask = (jnp.asarray(ix.row_mask, dtype=dtype)
+                    if ix.row_mask is not None else None)
+            weights = (mask if mask is not None
+                       else jnp.ones((sp.m,), dtype=dtype))
+            phi_circ_row = cnt["phi_circ"][row_to_group]
+            if use_kernel:
+                # Fused pass: masking (data_scale), scan, tail, √Φ° scaling and
+                # start-row zeroing in one kernel; heads gathered from the
+                # segment-final inclusive sums.
+                last = (jnp.asarray(ix.group_start)
+                        + jnp.asarray(ix.group_count) - 1)
+                live = jnp.asarray(ix.group_count) > 0
+                slab, heads, _ = nf_ops.fused_node_pass(
+                    x, weights, pos_in_group, jnp.sqrt(phi_circ_row), last,
+                    live, data_scale=mask)
+                tail_slabs[idx] = slab
+            else:
+                if mask is not None:
+                    x = x * mask[:, None]
+                heads, tails, _ = segmented_head_tail(
+                    x, weights, row_to_group, pos_in_group, sp.K)
+                tail_slabs[idx] = tails * jnp.sqrt(phi_circ_row)[:, None]
 
-        scales = jnp.sqrt(cnt["rpk"])  # √|S_i^x̄|, one per key
         # --- PROCESS_AND_JOIN_CHILDREN (lines 17-26) ----------------------
-        if sp.children:
-            gathered = []  # (data [K, w_ch], scale [K]) in child (column) order
-            for ch in sp.children:
-                lookup = jnp.asarray(ix.child_lookup[ch])
-                gathered.append((carried_data.pop(ch)[lookup],
-                                 carried_scales.pop(ch)[lookup]))
-            prod_all = functools.reduce(jnp.multiply, [s for _, s in gathered])
-            blocks = [heads * prod_all[:, None]]
-            for j, (dj, _) in enumerate(gathered):
-                prod_except = functools.reduce(
-                    jnp.multiply,
-                    [s for k, (_, s) in enumerate(gathered) if k != j],
-                    scales)  # scales = √rpk_i  (line 24's `scales[x̄_i]` factor)
-                blocks.append(dj * prod_except[:, None])
-            # Children subtrees are column-contiguous after the node's own
-            # columns (validated at plan build) — Data is a pure concat.
-            data_mat = jnp.concatenate(blocks, axis=1)
-            scales = scales * prod_all  # line 26
-        else:
-            data_mat = heads  # width == n for a leaf
+        with jax.named_scope("figaro.join_children"):
+            scales = jnp.sqrt(cnt["rpk"])  # √|S_i^x̄|, one per key
+            if sp.children:
+                # (data [K, w_ch], scale [K]) in child (column) order
+                gathered = []
+                for ch in sp.children:
+                    lookup = jnp.asarray(ix.child_lookup[ch])
+                    gathered.append((carried_data.pop(ch)[lookup],
+                                     carried_scales.pop(ch)[lookup]))
+                prod_all = functools.reduce(jnp.multiply,
+                                            [s for _, s in gathered])
+                blocks = [heads * prod_all[:, None]]
+                for j, (dj, _) in enumerate(gathered):
+                    # scales = √rpk_i (line 24's `scales[x̄_i]` factor)
+                    prod_except = functools.reduce(
+                        jnp.multiply,
+                        [s for k, (_, s) in enumerate(gathered) if k != j],
+                        scales)
+                    blocks.append(dj * prod_except[:, None])
+                # Children subtrees are column-contiguous after the node's own
+                # columns (validated at plan build) — Data is a pure concat.
+                data_mat = jnp.concatenate(blocks, axis=1)
+                scales = scales * prod_all  # line 26
+            else:
+                data_mat = heads  # width == n for a leaf
 
         # --- PROJECT_AWAY_JOIN_ATTRIBUTES (lines 27-34) / root (lines 7-8) -
-        if sp.parent >= 0:
-            group_to_pgroup = jnp.asarray(ix.group_to_pgroup)
-            pos_in_pgroup = jnp.asarray(ix.pos_in_pgroup)
-            phi_up_group = cnt["phi_up"][group_to_pgroup]
-            if use_kernel:
-                # Dead group slots continue the last live pgroup's segment
-                # with scale 0, so the segment-final gather index may safely
-                # land on them — the inclusive sums are unchanged past the
-                # last live member.
-                last = jax.ops.segment_max(
-                    jnp.arange(sp.K), group_to_pgroup, num_segments=sp.P,
-                    indices_are_sorted=True)
-                live = jnp.asarray(ix.pgroup_count) > 0
-                slab, gheads, _ = nf_ops.fused_node_pass(
-                    data_mat, scales, pos_in_pgroup, jnp.sqrt(phi_up_group),
-                    last, live)
-                out_slabs[idx] = slab
+        with jax.named_scope("figaro.project"):
+            if sp.parent >= 0:
+                group_to_pgroup = jnp.asarray(ix.group_to_pgroup)
+                pos_in_pgroup = jnp.asarray(ix.pos_in_pgroup)
+                phi_up_group = cnt["phi_up"][group_to_pgroup]
+                if use_kernel:
+                    # Dead group slots continue the last live pgroup's
+                    # segment with scale 0, so the segment-final gather index
+                    # may safely land on them — the inclusive sums are
+                    # unchanged past the last live member.
+                    last = jax.ops.segment_max(
+                        jnp.arange(sp.K), group_to_pgroup, num_segments=sp.P,
+                        indices_are_sorted=True)
+                    live = jnp.asarray(ix.pgroup_count) > 0
+                    slab, gheads, _ = nf_ops.fused_node_pass(
+                        data_mat, scales, pos_in_pgroup,
+                        jnp.sqrt(phi_up_group), last, live)
+                    out_slabs[idx] = slab
+                else:
+                    gheads, gtails, _ = segmented_head_tail(
+                        data_mat, scales, group_to_pgroup, pos_in_pgroup, sp.P)
+                    out_slabs[idx] = gtails * jnp.sqrt(phi_up_group)[:, None]
+                carried_data[idx] = gheads
+                carried_scales[idx] = jnp.sqrt(cnt["phi_down"])
             else:
-                gheads, gtails, _ = segmented_head_tail(
-                    data_mat, scales, group_to_pgroup, pos_in_pgroup, sp.P)
-                out_slabs[idx] = gtails * jnp.sqrt(phi_up_group)[:, None]
-            carried_data[idx] = gheads
-            carried_scales[idx] = jnp.sqrt(cnt["phi_down"])
-        else:
-            out_slabs[idx] = data_mat
+                out_slabs[idx] = data_mat
 
-    if assembly == "band":
-        r0 = _assemble_band(spec, tail_slabs, out_slabs)
-    else:
-        r0 = _assemble_padded(spec, tail_slabs, out_slabs)
+    with jax.named_scope("figaro.assemble"):
+        if assembly == "band":
+            r0 = _assemble_band(spec, tail_slabs, out_slabs)
+        else:
+            r0 = _assemble_padded(spec, tail_slabs, out_slabs)
     assert r0.shape == (spec.r0_rows, spec.num_cols), (r0.shape, spec.r0_rows)
     return r0
 

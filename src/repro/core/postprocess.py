@@ -187,19 +187,22 @@ def tsqr_r(a: jnp.ndarray, leaf_rows: int = 256,
 def postprocess_r0(r0: jnp.ndarray, *, method: str = "tsqr",
                    leaf_rows: int = 256, panel: int = 32,
                    use_kernel: bool = False) -> jnp.ndarray:
-    """R₀ (M×N, almost upper-triangular) → R (N×N, diag ≥ 0)."""
-    if method == "tsqr":
-        leaf = functools.partial(blocked_qr_r, panel=panel, use_kernel=use_kernel) \
-            if use_kernel else householder_qr_r
-        r = tsqr_r(r0, leaf_rows=leaf_rows, leaf_qr=leaf)
-    elif method == "householder":
-        r = householder_qr_r(r0)
-    elif method == "blocked":
-        r = blocked_qr_r(r0, panel=panel, use_kernel=use_kernel)
-    elif method == "lapack":  # XLA's native QR (the openblas/MKL analog)
-        r = jnp.linalg.qr(r0, mode="r")
-        n = r0.shape[1]
-        r = r[:n]
-    else:
-        raise ValueError(f"unknown postprocess method {method!r}")
-    return normalize_sign(r)
+    """R₀ (M×N, almost upper-triangular) → R (N×N, diag ≥ 0), under the
+    ``figaro.postprocess`` named scope."""
+    with jax.named_scope("figaro.postprocess"):
+        if method == "tsqr":
+            leaf = functools.partial(blocked_qr_r, panel=panel,
+                                     use_kernel=use_kernel) \
+                if use_kernel else householder_qr_r
+            r = tsqr_r(r0, leaf_rows=leaf_rows, leaf_qr=leaf)
+        elif method == "householder":
+            r = householder_qr_r(r0)
+        elif method == "blocked":
+            r = blocked_qr_r(r0, panel=panel, use_kernel=use_kernel)
+        elif method == "lapack":  # XLA's native QR (the openblas/MKL analog)
+            r = jnp.linalg.qr(r0, mode="r")
+            n = r0.shape[1]
+            r = r[:n]
+        else:
+            raise ValueError(f"unknown postprocess method {method!r}")
+        return normalize_sign(r)

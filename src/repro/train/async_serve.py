@@ -25,6 +25,15 @@ serving layer into a small pipeline:
     different program from the one asked for, so it is never silent: every
     isolated re-dispatch is counted in ``stats()``, the batch's exception is
     kept there, and a warning is logged;
+  * every step of a batch runs under a `jax.profiler.TraceAnnotation` named
+    ``figaro.serve.<step>`` that carries the batch's sequence number
+    (``batch``) and live request count (``requests``): ``coalesce`` (taking
+    the group and concatenating its payloads), ``depth_wait`` (waiting for a
+    free ``queue_depth`` slot), ``stage``, ``launch`` on the dispatch thread;
+    ``ready`` (waiting for the device) and ``resolve`` (slicing the results
+    out, resolving the futures) on the completion thread. With the profiler
+    off a span costs one annotation object. ``stats()`` counts at the same
+    boundaries, always on;
   * ``append(node, rows)`` joins the same stream — it drains in-flight work,
     then refreshes the shared `plan_cache.PlanHolder` (zero retraces while
     live sizes stay within capacity), so the owning `JoinDataset`'s plan and
@@ -38,9 +47,11 @@ from __future__ import annotations
 
 import concurrent.futures
 import functools
+import itertools
 import logging
 import queue
 import threading
+import time
 import weakref
 
 import jax
@@ -98,10 +109,12 @@ class FigaroFuture(concurrent.futures.Future):
 class _Request:
     """One queue entry: a validated (or failed-at-validation) request."""
 
-    __slots__ = ("future", "arrays", "b", "single", "sig", "plan", "error")
+    __slots__ = ("future", "arrays", "b", "single", "sig", "plan", "error",
+                 "submitted")
 
     def __init__(self):
         self.future = FigaroFuture()
+        self.submitted = time.perf_counter()
         self.arrays = None  # capacity-shaped [b, m_i, n_i] leaves
         self.b = 0
         self.single = False  # squeeze the leading axis on resolve
@@ -115,6 +128,11 @@ class _Request:
 
 
 _SHUTDOWN = object()
+_span = jax.profiler.TraceAnnotation
+#: The always-on counters of `AsyncFigaroServer.stats` (class docstring).
+_COUNTERS = ("dispatches", "dispatched_requests", "dispatched_rows",
+             "capacity_rows", "queue_wait_s", "queue_wait_max_s",
+             "dispatch_host_s", "dispatch_host_max_s")
 
 
 def _slice_out(out, offset: int, b: int, single: bool):
@@ -206,8 +224,8 @@ def _complete_loop(server_ref, out_q):
 
 
 @shared_state({"_outstanding": "_cond", "_isolated": "_cond",
-               "_batch_error": "_cond", "_closed": "_close_lock",
-               "_threads": "_thread_lock"})
+               "_batch_error": "_cond", "_counters": "_cond",
+               "_closed": "_close_lock", "_threads": "_thread_lock"})
 class AsyncFigaroServer:
     """Pipelined micro-batching serving endpoint for one join structure.
 
@@ -229,7 +247,15 @@ class AsyncFigaroServer:
     ``stats()``
         ``isolated_redispatches`` (requests re-dispatched alone after their
         coalesced batch failed; 0 on a healthy path) and ``batch_error``
-        (the last failed batch's exception, or None).
+        (the last failed batch's exception, or None). Since construction:
+        ``dispatches`` (coalesced batches handed to the engine),
+        ``dispatched_requests``, ``dispatched_rows`` and ``capacity_rows``
+        (live request rows, and the padded bucket capacity they ran at);
+        ``queue_wait_s`` summed over requests from `submit` to the start of
+        their batch's launch (coalescing, the ``queue_depth`` wait and
+        staging included) and ``queue_wait_max_s``; ``dispatch_host_s``, the
+        dispatch thread's own time per batch (coalesce, stage and launch,
+        not the ``queue_depth`` wait), summed, and ``dispatch_host_max_s``.
     ``flush()`` / ``close()`` / ``pause()`` / ``resume()``
         Drain outstanding requests; shut the worker threads down; hold /
         release the coalescer (pause + submit + resume dispatches one
@@ -272,6 +298,8 @@ class AsyncFigaroServer:
         self._outstanding = 0
         self._isolated = 0
         self._batch_error: BaseException | None = None
+        self._counters = dict.fromkeys(_COUNTERS, 0)
+        self._batch_seq = itertools.count()  # the dispatch thread's alone
         self._closed = False
         self._threads: list[threading.Thread] | None = None
         self._finalizer = weakref.finalize(self, self._in_q.put, _SHUTDOWN)
@@ -396,57 +424,93 @@ class AsyncFigaroServer:
         seed the next group (or _SHUTDOWN, passed through). The pause() gate
         was already waited out by the dispatch loop (without a strong server
         reference), so the queue behind ``first`` is fully drained here."""
-        group = [first]
-        live_sig = first.sig if first.error is None else None
-        total_b = first.b if first.error is None else 0
-        leftover = None
-        while total_b < self.max_batch:
+        t_first = time.perf_counter()
+        seq = next(self._batch_seq)
+        with _span("figaro.serve.coalesce", batch=seq) as span:
+            group = [first]
+            live_sig = first.sig if first.error is None else None
+            total_b = first.b if first.error is None else 0
+            leftover = None
+            while total_b < self.max_batch:
+                try:
+                    nxt = self._in_q.get_nowait()
+                except queue.Empty:
+                    break
+                # Stop at a shutdown sentinel, an incompatible request, or a
+                # sub-batch that would push the group past max_batch (a
+                # single oversized submit still dispatches alone — it cannot
+                # be split); the popped item seeds the next group, preserving
+                # FIFO order.
+                if nxt is _SHUTDOWN or (nxt.error is None and (
+                        (live_sig is not None and nxt.sig != live_sig)
+                        or total_b + nxt.b > self.max_batch)):
+                    leftover = nxt
+                    break
+                group.append(nxt)
+                if nxt.error is None:
+                    live_sig = live_sig or nxt.sig
+                    total_b += nxt.b
+            live = [it for it in group if it.error is None]
+            n = len(live)
+            span.set_metadata(requests=n)
+            payload = data = None
             try:
-                nxt = self._in_q.get_nowait()
-            except queue.Empty:
-                break
-            # Stop at a shutdown sentinel, an incompatible request, or a
-            # sub-batch that would push the group past max_batch (a single
-            # oversized submit still dispatches alone — it cannot be split);
-            # the popped item seeds the next group, preserving FIFO order.
-            if nxt is _SHUTDOWN or (nxt.error is None and (
-                    (live_sig is not None and nxt.sig != live_sig)
-                    or total_b + nxt.b > self.max_batch)):
-                leftover = nxt
-                break
-            group.append(nxt)
-            if nxt.error is None:
-                live_sig = live_sig or nxt.sig
-                total_b += nxt.b
-        live = [it for it in group if it.error is None]
-        payload = None
-        self._depth_sem.acquire()  # ≤ queue_depth coalesced batches in flight
-        if live:
-            try:
-                if len(live) == 1:
+                if n == 1:
                     data = live[0].arrays
-                else:
+                elif n:
                     data = tuple(
                         np.concatenate([np.asarray(it.arrays[j])
                                         for it in live])
                         for j in range(len(live[0].arrays)))
+            except Exception as e:
+                payload = (None, e)
+        t_coalesced = time.perf_counter()
+        with _span("figaro.serve.depth_wait", batch=seq, requests=n):
+            self._depth_sem.acquire()  # ≤ queue_depth batches in flight
+        t_slot = t_launch = time.perf_counter()
+        capacity = self._capacity_for(total_b)
+        if live and payload is None:
+            try:
                 if self._engine_stage is not None:
-                    data = self._engine_stage(data)
-                out = self._dispatch_fn(live[0].plan, data,
-                                        self._capacity_for(total_b) or None)
+                    with _span("figaro.serve.stage", batch=seq, requests=n):
+                        data = self._engine_stage(data)
+                t_launch = time.perf_counter()
+                with _span("figaro.serve.launch", batch=seq, requests=n):
+                    out = self._dispatch_fn(live[0].plan, data,
+                                            capacity or None)
                 payload = (out, None)
             except Exception as e:
                 payload = (None, e)
-        self._out_q.put((group, live, payload))
+        host = t_coalesced - t_first + time.perf_counter() - t_slot
+        if live:
+            waits = [t_launch - it.submitted for it in live]
+            with self._cond:
+                c = self._counters
+                c["dispatches"] += 1
+                c["dispatched_requests"] += n
+                c["dispatched_rows"] += total_b
+                c["capacity_rows"] += capacity
+                c["queue_wait_s"] += sum(waits)
+                c["queue_wait_max_s"] = max(c["queue_wait_max_s"], *waits)
+                c["dispatch_host_s"] += host
+                c["dispatch_host_max_s"] = max(c["dispatch_host_max_s"], host)
+        self._out_q.put((group, live, payload, seq))
         return leftover
 
-    def _resolve_group(self, group, live, payload) -> None:
+    def _resolve_group(self, group, live, payload, seq) -> None:
         out, err = payload if payload is not None else (None, None)
+        n = len(live)
         if err is None and out is not None:
             try:
-                jax.block_until_ready(out)
+                with _span("figaro.serve.ready", batch=seq, requests=n):
+                    jax.block_until_ready(out)
             except Exception as e:
                 err, out = e, None
+        with _span("figaro.serve.resolve", batch=seq, requests=n):
+            self._resolve(group, live, out, err)
+        self._depth_sem.release()
+
+    def _resolve(self, group, live, out, err) -> None:
         results, errors = {}, {}
         if live and err is None and out is not None:
             offset = 0
@@ -481,13 +545,13 @@ class AsyncFigaroServer:
             else:
                 it.future._resolve(error=errors.get(id(it), err))
             self._done_one()
-        self._depth_sem.release()
 
     def stats(self) -> dict:
         """Serving counters (see the class docstring)."""
         with self._cond:
-            return {"isolated_redispatches": self._isolated,
-                    "batch_error": self._batch_error}
+            return dict(self._counters,
+                        isolated_redispatches=self._isolated,
+                        batch_error=self._batch_error)
 
     def _fail_item(self, item, error: BaseException) -> None:
         if isinstance(item, _Request) and not item.future.done():

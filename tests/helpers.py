@@ -2,13 +2,15 @@
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 
 from repro.core.join_tree import JoinTree, build_plan
 from repro.core.materialize import materialize_join
 from repro.core.relation import Database, full_reduce
 
-__all__ = ["random_acyclic_db", "r_close", "TOPOLOGIES"]
+__all__ = ["random_acyclic_db", "r_close", "TOPOLOGIES", "hlo_instructions"]
 
 # (name, edges, root) — relation names are S1..S4; key attrs named for edges.
 TOPOLOGIES = {
@@ -65,3 +67,16 @@ def r_close(r_a, r_b, *, rtol=1e-9) -> bool:
 
 def materialized(tree: JoinTree) -> np.ndarray:
     return np.asarray(materialize_join(tree))
+
+
+_SOURCE_TABLE = re.compile(
+    r"(FileNames|FunctionNames|FileLocations|StackFrames)$|\d+ ")
+
+
+def hlo_instructions(hlo: str) -> str:
+    """An optimized HLO text without its metadata and its tables of source
+    locations (which the CPU prints after the computations, the TPU before):
+    what the compiler made, not where it came from."""
+    body = "\n".join(line for line in hlo.splitlines()
+                     if not _SOURCE_TABLE.match(line))
+    return re.sub(r",? metadata=\{[^}]*\}", "", body)

@@ -251,8 +251,8 @@ def test_isolated_redispatches_are_counted(rng):
     server.resume()
     for f in futures:
         f.result(timeout=60)
-    assert server.stats() == {"isolated_redispatches": 0,
-                              "batch_error": None}
+    stats = server.stats()
+    assert (stats["isolated_redispatches"], stats["batch_error"]) == (0, None)
 
     server._dispatch_fn = flaky
     server.pause()
@@ -265,6 +265,32 @@ def test_isolated_redispatches_are_counted(rng):
     assert stats["isolated_redispatches"] == 3
     assert isinstance(stats["batch_error"], RuntimeError)
     assert "coalesced batch failed" in str(stats["batch_error"])
+    server.close()
+
+
+def test_stats_count_batches_rows_and_waits(rng):
+    """Three requests held by pause() coalesce into one dispatch at bucket
+    capacity 4; each request's wait from submit to launch is counted."""
+    plan = build_plan(_star_tree())
+    server = make_figaro_server(plan, kind="qr", dtype=jnp.float64,
+                                engine=FigaroEngine(donate_data=False))
+    server.pause()
+    futures = [server.submit(r) for r in _requests(plan, rng, 3)]
+    server.resume()
+    for f in futures:
+        f.result(timeout=60)
+    stats = server.stats()
+    assert stats["dispatches"] == 1
+    assert stats["dispatched_requests"] == 3
+    assert stats["dispatched_rows"] == 3
+    assert stats["capacity_rows"] == 4
+    assert 0 < stats["queue_wait_max_s"] <= stats["queue_wait_s"]
+    assert 0 < stats["dispatch_host_max_s"] == stats["dispatch_host_s"]
+    server.submit(_requests(plan, rng, 1)[0]).result(timeout=60)
+    after = server.stats()
+    assert (after["dispatches"], after["dispatched_requests"],
+            after["capacity_rows"]) == (2, 4, 5)
+    assert after["dispatch_host_s"] >= after["dispatch_host_max_s"]
     server.close()
 
 

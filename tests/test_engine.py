@@ -1,6 +1,7 @@
 """Compiled FiGaRo engine: plan-as-pytree jit, batched serving, cache hits,
 and the scatter-free R₀ assembly path."""
 
+import contextlib
 import re
 
 import jax
@@ -14,7 +15,7 @@ from repro.core.join_tree import build_plan
 from repro.core.materialize import materialize_join
 from repro.data.relational import cartesian
 
-from helpers import random_acyclic_db
+from helpers import hlo_instructions, random_acyclic_db
 
 # Batched-vs-per-sample coverage: a path join, a star join, and a Cartesian
 # edge (constant keys => the degenerate single-group path).
@@ -367,3 +368,47 @@ def test_sharded_dispatch_single_request_batch(rng):
                                    dtype=jnp.float64))
     assert r_shard.shape[0] == 1
     np.testing.assert_allclose(r_shard[0], r_plain, atol=1e-12)
+
+
+# -- phase scopes: metadata only ---------------------------------------------
+
+ALGORITHM_2 = ("figaro.counts", "figaro.heads_tails", "figaro.join_children",
+               "figaro.project", "figaro.assemble", "figaro.postprocess")
+KIND_OPTIONS = {"qr": {}, "pca": {"k": 2, "center": True}}
+
+
+def _compiled_hlo(kind, plan, data):
+    """Optimized HLO of the batched ``kind`` program as the engine jits it."""
+    engine = FigaroEngine(donate_data=False)
+    impl = getattr(engine, f"_{kind}_batched_impl")
+    options = dict(KIND_OPTIONS[kind], dtype=np.dtype(np.float64),
+                   method="tsqr", leaf_rows=256, panel=32, use_kernel=False,
+                   assembly="padded")
+    fn = jax.jit(lambda p, d: impl(p, d, **options))
+    return fn.lower(plan.without_data(), data).compile().as_text()
+
+
+@pytest.mark.parametrize("kind", list(KIND_OPTIONS))
+def test_every_phase_scope_reaches_the_op_metadata(rng, kind):
+    _, plan = _plan("star", rng)
+    hlo = _compiled_hlo(kind, plan, _batch(plan, rng, 2, np.float64))
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    want = ALGORITHM_2 + (("figaro.downstream",) if kind == "pca" else ())
+    for scope in want:
+        assert any(re.search(rf"(^|[/(]){re.escape(scope)}[/)]", n)
+                   for n in names), scope
+    node = plan.spec.nodes[plan.spec.root].name
+    assert any(f"figaro.heads_tails)/{node}/" in n for n in names), node
+
+
+def test_phase_scopes_leave_the_compiled_program_unchanged(rng, monkeypatch):
+    """Without its named scopes the batched qr program compiles to the same
+    optimized HLO, once the metadata is stripped."""
+    _, plan = _plan("star", rng)
+    data = _batch(plan, rng, 2, np.float64)
+    scoped = hlo_instructions(_compiled_hlo("qr", plan, data))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = hlo_instructions(_compiled_hlo("qr", plan, data))
+    assert "figaro." not in plain and scoped.count("fusion(") > 20
+    assert scoped == plain

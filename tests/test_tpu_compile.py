@@ -11,6 +11,7 @@ only one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
 
+import contextlib
 import os
 
 import jax
@@ -25,6 +26,8 @@ from repro.data.relational import yelp_like
 from repro.kernels import _platform
 from repro.kernels.node_fused.kernel import node_fused_kernel
 from repro.kernels.panel_qr.kernel import panel_qr_kernel
+
+from helpers import hlo_instructions
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +81,24 @@ def test_panel_qr_compiles_at_tsqr_leaf(one_chip):
 def test_qr_engine_program_compiles(one_chip, tpu_backend, use_kernel):
     """The whole batched qr program the async server dispatches (B=4,
     float32), for a small capacity plan."""
+    compiled = _qr_program(one_chip, use_kernel)
+    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+
+
+def test_phase_scopes_leave_the_tpu_program_unchanged(one_chip, tpu_backend,
+                                                      monkeypatch):
+    """The named phase scopes are metadata: without them the TPU compiler
+    makes the same fusions under the same names."""
+    scoped = _qr_program(one_chip, False).as_text()
+    assert "figaro.heads_tails" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _qr_program(one_chip, False).as_text()
+    assert hlo_instructions(scoped).count("fusion(") > 100
+    assert hlo_instructions(scoped) == hlo_instructions(plain)
+
+
+def _qr_program(one_chip, use_kernel):
     plan = build_capacity_plan(yelp_like())
     as_spec = lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip)
     plan_spec = jax.tree.map(as_spec, plan.without_data())
@@ -85,11 +106,10 @@ def test_qr_engine_program_compiles(one_chip, tpu_backend, use_kernel):
                       for d in plan.data)
     program = FigaroEngine()._make_jitted("qr_batched", False, None, None,
                                           None)
-    compiled = program.lower(
+    return program.lower(
         plan_spec, data_spec, dtype=np.dtype(np.float32), method="tsqr",
         leaf_rows=256, panel=32, use_kernel=use_kernel,
         assembly="padded").compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
 
 
 @pytest.mark.parametrize("kernel", ["node_fused", "panel_qr"])
