@@ -70,9 +70,10 @@ def _scatter_r0(plan, data, *, dtype=jnp.float64):
         cnt = counts[idx]
         x = data[idx]
         ones = jnp.ones((sp.m,), dtype=dtype)
+        group_count = jnp.asarray(ix.group_count)
         heads, tails, _ = segmented_head_tail(
-            x, ones, jnp.asarray(ix.row_to_group),
-            jnp.asarray(ix.pos_in_group), sp.K)
+            x, ones, jnp.asarray(ix.pos_in_group),
+            jnp.asarray(ix.group_start) + group_count - 1, group_count > 0)
         phi_circ_row = cnt["phi_circ"][jnp.asarray(ix.row_to_group)]
         emit(sp.col_start, tails * jnp.sqrt(phi_circ_row)[:, None])
         scales = jnp.sqrt(cnt["rpk"])
@@ -98,9 +99,11 @@ def _scatter_r0(plan, data, *, dtype=jnp.float64):
         else:
             data_mat = heads
         if sp.parent >= 0:
+            pgroup_count = jnp.asarray(ix.pgroup_count)
+            last = jnp.cumsum(pgroup_count, dtype=pgroup_count.dtype) - 1
             gheads, gtails, _ = segmented_head_tail(
-                data_mat, scales, jnp.asarray(ix.group_to_pgroup),
-                jnp.asarray(ix.pos_in_pgroup), sp.P)
+                data_mat, scales, jnp.asarray(ix.pos_in_pgroup), last,
+                pgroup_count > 0)
             phi_up_group = cnt["phi_up"][jnp.asarray(ix.group_to_pgroup)]
             emit(sp.subtree_start, gtails * jnp.sqrt(phi_up_group)[:, None])
             carried_data[idx] = gheads

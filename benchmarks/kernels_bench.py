@@ -25,12 +25,15 @@ from ._util import Csv, timeit, write_bench_json
 
 
 def _segments(rng, m):
-    """Sorted segment ids + position-within-segment for m rows."""
+    """Random contiguous segments over m rows in m // 16 slots, some empty:
+    position within segment [m], each slot's last row and liveness [K]."""
     seg = np.sort(rng.integers(0, m // 16, size=m)).astype(np.int32)
     pos = np.zeros(m, np.int32)
     pos[1:] = np.where(seg[1:] == seg[:-1], 1, 0)
     pos = np.cumsum(pos) * (pos > 0)
-    return seg, pos
+    count = np.bincount(seg, minlength=m // 16)
+    last = np.maximum(np.cumsum(count) - 1, 0).astype(np.int32)
+    return jnp.array(pos), jnp.array(last), jnp.array(count > 0)
 
 
 def run(csv: Csv, *, fast: bool = False) -> None:
@@ -46,8 +49,7 @@ def run(csv: Csv, *, fast: bool = False) -> None:
     for m, n in sizes:
         data = jnp.array(rng.normal(size=(m, n)), jnp.float32)
         w = jnp.array(rng.uniform(0.5, 2.0, size=m), jnp.float32)
-        seg, pos = _segments(rng, m)
-        args = (data, w, jnp.array(seg), jnp.array(pos), int(seg.max()) + 1)
+        args = (data, w, *_segments(rng, m))
         t = timeit(lambda: segmented_head_tail(*args))
         case = f"headtail_{m}x{n}"
         add(case, "xla_path_s", t)
@@ -63,14 +65,9 @@ def run(csv: Csv, *, fast: bool = False) -> None:
     for m, n in [(4096, 64)] if fast else [(4096, 64), (16384, 64)]:
         data = jnp.array(rng.normal(size=(m, n)), jnp.float32)
         w = jnp.array(rng.uniform(0.5, 2.0, size=m), jnp.float32)
-        seg, pos = _segments(rng, m)
-        num_seg = int(seg.max()) + 1
-        pos_j = jnp.array(pos)
+        pos, last, live = _segments(rng, m)
         emit = jnp.array(rng.uniform(0.5, 2.0, size=m), jnp.float32)
-        starts = np.nonzero(np.r_[True, seg[1:] != seg[:-1]])[0]
-        last = jnp.array(np.r_[starts[1:] - 1, m - 1].astype(np.int32))
-        live = jnp.ones((num_seg,), bool)
-        f_args = (data, w, pos_j, emit, last, live)
+        f_args = (data, w, pos, emit, last, live)
         t_ref = timeit(lambda: fused_node_pass_ref(*f_args))
         case = f"node_fused_{m}x{n}"
         add(case, "xla_ref_s", t_ref)

@@ -29,10 +29,11 @@ Two hot-path variants, both cache-keyed by the engine:
   * ``use_kernel=True`` routes each node's two head/tail passes through the
     fused `kernels/node_fused` Pallas kernel: live-row masking, the weighted
     segmented scan, the tail formula, segment-start zeroing and √Φ emission
-    scaling collapse into one HBM round-trip per pass, and the heads come
-    from an O(m) gather of the kernel's inclusive sums instead of a second
-    [m, n] reduction. ``use_kernel=False`` (default) is the XLA path —
-    `segmented_head_tail` per pass — which stays the CPU fallback.
+    scaling collapse into one HBM round-trip per pass. ``use_kernel=False``
+    (default) is the XLA path — `segmented_head_tail` per pass — which stays
+    the CPU fallback. Both paths take the heads from the inclusive sums at
+    each segment's last row, a gather of K indices, instead of a second
+    [m, n] reduction.
 
   * ``assembly`` picks how the emitted slabs become R₀. ``"padded"``
     (default) pads every slab to the full ``num_cols`` width and concatenates
@@ -193,13 +194,14 @@ def figaro_r0(
             weights = (mask if mask is not None
                        else jnp.ones((sp.m,), dtype=dtype))
             phi_circ_row = cnt["phi_circ"][row_to_group]
+            # Heads are read at each group's last row (a dead group slot
+            # points at the last live row and is zeroed by `live`).
+            group_count = jnp.asarray(ix.group_count)
+            last = jnp.asarray(ix.group_start) + group_count - 1
+            live = group_count > 0
             if use_kernel:
                 # Fused pass: masking (data_scale), scan, tail, √Φ° scaling and
-                # start-row zeroing in one kernel; heads gathered from the
-                # segment-final inclusive sums.
-                last = (jnp.asarray(ix.group_start)
-                        + jnp.asarray(ix.group_count) - 1)
-                live = jnp.asarray(ix.group_count) > 0
+                # start-row zeroing in one kernel.
                 slab, heads, _ = nf_ops.fused_node_pass(
                     x, weights, pos_in_group, jnp.sqrt(phi_circ_row), last,
                     live, data_scale=mask)
@@ -208,7 +210,7 @@ def figaro_r0(
                 if mask is not None:
                     x = x * mask[:, None]
                 heads, tails, _ = segmented_head_tail(
-                    x, weights, row_to_group, pos_in_group, sp.K)
+                    x, weights, pos_in_group, last, live)
                 tail_slabs[idx] = tails * jnp.sqrt(phi_circ_row)[:, None]
 
         # --- PROCESS_AND_JOIN_CHILDREN (lines 17-26) ----------------------
@@ -258,8 +260,15 @@ def figaro_r0(
                         jnp.sqrt(phi_up_group), last, live)
                     out_slabs[idx] = slab
                 else:
+                    # Live groups are pgroup-sorted and dead group slots sit
+                    # after them, so pgroup p ends at its running group
+                    # total; a dead pgroup slot lands on the last live group.
+                    pgroup_count = jnp.asarray(ix.pgroup_count)
+                    last = jnp.cumsum(pgroup_count,
+                                      dtype=pgroup_count.dtype) - 1
                     gheads, gtails, _ = segmented_head_tail(
-                        data_mat, scales, group_to_pgroup, pos_in_pgroup, sp.P)
+                        data_mat, scales, pos_in_pgroup, last,
+                        pgroup_count > 0)
                     out_slabs[idx] = gtails * jnp.sqrt(phi_up_group)[:, None]
                 carried_data[idx] = gheads
                 carried_scales[idx] = jnp.sqrt(cnt["phi_down"])

@@ -119,58 +119,61 @@ def segmented_cumsum(x: jnp.ndarray, first_flag: jnp.ndarray) -> jnp.ndarray:
 def segmented_head_tail(
     data: jnp.ndarray,
     weights: jnp.ndarray,
-    seg_id: jnp.ndarray,
     pos_in_seg: jnp.ndarray,
-    num_segments: int,
+    last_of_seg: jnp.ndarray,
+    seg_live: jnp.ndarray,
     *,
     use_kernel: bool = False,
 ):
     """Per-segment generalized head & tail over contiguous row segments.
 
+    The segment totals are the inclusive sums at each segment's last row, so
+    the heads and norms are gathered there (K indices) rather than reduced a
+    second time over all m rows.
+
     Args:
       data: [m, n]; rows of all segments, concatenated (segment-sorted).
-      weights: [m] strictly positive weights ``v``.
-      seg_id: [m] int — segment of each row (non-decreasing).
+      weights: [m] weights ``v``: positive, or 0 on dead rows, which never
+        start a segment.
       pos_in_seg: [m] int — 0 for the first row of a segment.
-      num_segments: static segment count K.
-      use_kernel: route the segmented scan through the Pallas kernel
-        (`repro.kernels.head_tail`) instead of the XLA associative scan.
+      last_of_seg: [K] int — row index of each segment's last row.
+      seg_live: [K] bool — False for a segment slot that holds no rows; its
+        head and norm are 0 and its ``last_of_seg`` may be any row.
+      use_kernel: compute the tails with the Pallas kernel
+        (`repro.kernels.head_tail`) instead of from the Hillis–Steele
+        ladder's sums; the heads come from the ladder either way.
 
     Returns:
       heads: [K, n]   — H(seg, v_seg)
       tails: [m, n]   — row r holds T(seg, v_seg)[pos-1] for pos>0, else 0
       norms: [K]      — ‖v_seg‖₂ (the scaling Lemma 3.5 applies to the S part)
     """
-    m, _ = data.shape
     dtype = data.dtype
     weights = weights.astype(dtype)
     first = pos_in_seg == 0
     w2 = weights * weights
     wa = data * weights[:, None]
+    c_incl = segmented_cumsum(w2, first)
+    s_incl = segmented_cumsum(wa, first)
+    c_excl_safe = jnp.where(pos_in_seg > 0, c_incl - w2, 1.0)
 
     if use_kernel:
         from repro.kernels.head_tail import ops as ht_ops
-        c_incl = segmented_cumsum(w2, first)
-        c_excl = c_incl - w2
-        c_excl_safe = jnp.where(pos_in_seg > 0, c_excl, 1.0)
         coef_a = jnp.sqrt(c_excl_safe / c_incl)
         coef_b = -weights / jnp.sqrt(c_excl_safe * c_incl)
         tails = ht_ops.segmented_tail(data, wa, first, coef_a, coef_b)
     else:
-        c_incl = segmented_cumsum(w2, first)
-        s_incl = segmented_cumsum(wa, first)
-        c_excl = c_incl - w2
         s_excl = s_incl - wa
-        c_excl_safe = jnp.where(pos_in_seg > 0, c_excl, 1.0)
         tails = (jnp.sqrt(c_excl_safe)[:, None] * data
                  - weights[:, None] * s_excl / jnp.sqrt(c_excl_safe)[:, None])
         tails = tails / jnp.sqrt(c_incl)[:, None]
     tails = jnp.where((pos_in_seg > 0)[:, None], tails, jnp.zeros_like(tails))
 
-    c_tot = jax.ops.segment_sum(w2, seg_id, num_segments=num_segments)
-    s_tot = jax.ops.segment_sum(wa, seg_id, num_segments=num_segments)
-    norms = jnp.sqrt(c_tot)
-    heads = s_tot / jnp.where(norms > 0, norms, 1.0)[:, None]
+    # Each row's head-so-far; a segment's head is the one at its last row.
+    row_norms = jnp.sqrt(c_incl)
+    row_heads = s_incl / jnp.where(row_norms > 0, row_norms, 1.0)[:, None]
+    heads = jnp.where(seg_live[:, None], row_heads[last_of_seg], 0.0)
+    norms = jnp.where(seg_live, row_norms[last_of_seg], 0.0)
     return heads, tails, norms
 
 

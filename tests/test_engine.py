@@ -13,6 +13,7 @@ from repro.core.engine import FigaroEngine
 from repro.core.figaro import figaro_r0, figaro_r0_batched
 from repro.core.join_tree import build_plan
 from repro.core.materialize import materialize_join
+from repro.core.plan_cache import pad_plan
 from repro.data.relational import cartesian
 
 from helpers import hlo_instructions, random_acyclic_db
@@ -145,6 +146,27 @@ def test_r0_assembly_is_scatter_free(rng):
                 plan.without_data(), plan.data))
         assert "dynamic_update_slice" not in jaxpr, topology
         assert not re.search(r"\bscatter\[", jaxpr), topology
+
+
+@pytest.mark.parametrize("topology", list(BATCH_TOPOLOGIES))
+def test_node_passes_are_scatter_free(rng, topology):
+    """The XLA path reads each segment's head and norm from its inclusive
+    sums: no scatter lies under `figaro.heads_tails` or `figaro.project`,
+    on an exact and on a capacity-padded plan, per sample and batched.
+    (Algorithm 1's scatter-adds under `figaro.counts` stay.)"""
+    _, exact = _plan(topology, rng)
+    for plan in (exact, pad_plan(exact)):
+        batch = _batch(plan, rng, 2, np.float32)
+        for fn, data in ((figaro_r0, plan.data), (figaro_r0_batched, batch)):
+            hlo = jax.jit(lambda p, d, fn=fn: fn(p, list(d), use_kernel=False)
+                          ).lower(plan.without_data(), data).compile().as_text()
+            scatters = [re.search(r'op_name="([^"]*)"', line).group(1)
+                        for line in hlo.splitlines()
+                        if re.search(r"= \S+ scatter\(", line)]
+            assert "figaro.heads_tails" in hlo, (topology, fn.__name__)
+            for name in scatters:
+                assert not re.search(r"figaro\.(heads_tails|project)\b",
+                                     name), (topology, fn.__name__, name)
 
 
 def test_figaro_r0_jits_with_plan_argument(rng):

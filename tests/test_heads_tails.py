@@ -153,15 +153,21 @@ def test_segmented_cumsum_ladder_matches_loop(rng, m, width):
     np.testing.assert_allclose(out, expect, rtol=1e-12, atol=1e-12)
 
 
+def _segment_layout(sizes):
+    """(pos_in_seg, last_of_seg, seg_live) of contiguous segments of the
+    given sizes; a size-0 slot is dead and points at the row before it."""
+    sizes = np.asarray(sizes)
+    pos = np.concatenate([np.arange(s) for s in sizes])
+    last = np.maximum(np.cumsum(sizes) - 1, 0)
+    return jnp.array(pos), jnp.array(last), jnp.array(sizes > 0)
+
+
 def test_segmented_head_tail_matches_per_segment(rng):
     sizes = [3, 1, 5, 2]
     data = _rand(rng, sum(sizes), 4)
     w = rng.uniform(0.5, 2.0, size=sum(sizes))
-    seg = np.repeat(np.arange(len(sizes)), sizes)
-    pos = np.concatenate([np.arange(s) for s in sizes])
     heads, tails, norms = segmented_head_tail(
-        jnp.array(data), jnp.array(w), jnp.array(seg), jnp.array(pos),
-        len(sizes))
+        jnp.array(data), jnp.array(w), *_segment_layout(sizes))
     ofs = 0
     for k, s in enumerate(sizes):
         blk, vb = data[ofs:ofs + s], w[ofs:ofs + s]
@@ -177,3 +183,59 @@ def test_segmented_head_tail_matches_per_segment(rng):
         # first row of each segment carries no tail
         np.testing.assert_allclose(np.asarray(tails[ofs]), 0, atol=0)
         ofs += s
+
+
+def _np_head_tail(a, v):
+    """Definition 3.4 in float64 numpy, row by row: (head, [m-1, n] tail)."""
+    a, v = np.asarray(a, np.float64), np.asarray(v, np.float64)
+    h = (v @ a) / np.linalg.norm(v)
+    t = np.empty((a.shape[0] - 1, a.shape[1]))
+    for j in range(1, a.shape[0]):
+        nj, nj1 = np.linalg.norm(v[:j]), np.linalg.norm(v[:j + 1])
+        t[j - 1] = (nj * a[j] - v[j] * (v[:j] @ a[:j]) / nj) / nj1
+    return h, t
+
+
+# Live segment sizes and trailing dead rows / dead group slots, laid out as a
+# capacity-padded plan does: dead rows extend the last live segment with
+# weight 0 and zeroed data, dead slots hold no rows.
+SEGMENT_LAYOUTS = {
+    "one_row": ([1] * 12, 0, 0),
+    "long_uneven": ([1, 17, 2, 40, 1, 9, 33], 0, 0),
+    "capacity_padded": ([4, 1, 6, 2], 5, 3),
+}
+
+
+@pytest.mark.parametrize("layout", list(SEGMENT_LAYOUTS))
+@pytest.mark.parametrize("dtype,tol", [(np.float32, 1e-5), (np.float64, 1e-12)])
+def test_segmented_head_tail_gathers_segment_totals(rng, layout, dtype, tol):
+    """Heads and norms read at each segment's last row equal the per-segment
+    Definition 3.4; dead rows emit zero tails, dead slots zero heads and
+    norms."""
+    live_sizes, dead_rows, dead_slots = SEGMENT_LAYOUTS[layout]
+    m_live = sum(live_sizes)
+    m = m_live + dead_rows
+    data = _rand(rng, m, 3)
+    data[m_live:] = 0.0
+    w = rng.uniform(0.5, 2.0, size=m)
+    w[m_live:] = 0.0
+    sizes = live_sizes[:-1] + [live_sizes[-1] + dead_rows] + [0] * dead_slots
+    heads, tails, norms = segmented_head_tail(
+        jnp.array(data, dtype), jnp.array(w, dtype), *_segment_layout(sizes))
+    heads, tails, norms = map(np.asarray, (heads, tails, norms))
+    assert heads.dtype == tails.dtype == norms.dtype == dtype
+    assert heads.shape == (len(sizes), 3) and norms.shape == (len(sizes),)
+    ofs = 0
+    for k, s in enumerate(live_sizes):
+        h, t = _np_head_tail(data[ofs:ofs + s], w[ofs:ofs + s])
+        scale = max(np.abs(data[ofs:ofs + s]).max(), 1.0)
+        np.testing.assert_allclose(heads[k], h, rtol=tol, atol=tol * scale)
+        np.testing.assert_allclose(norms[k], np.linalg.norm(w[ofs:ofs + s]),
+                                   rtol=tol)
+        np.testing.assert_allclose(tails[ofs + 1:ofs + s], t, rtol=tol,
+                                   atol=tol * scale)
+        assert not tails[ofs].any()
+        ofs += s
+    assert not tails[m_live:].any()
+    assert not heads[len(live_sizes):].any()
+    assert not norms[len(live_sizes):].any()

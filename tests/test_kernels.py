@@ -45,7 +45,9 @@ def test_head_tail_kernel_integrated(rng):
     pos = np.zeros(m, np.int32)
     for i in range(1, m):
         pos[i] = pos[i - 1] + 1 if seg[i] == seg[i - 1] else 0
-    args = (data, w, jnp.array(seg), jnp.array(pos), 12)
+    count = np.bincount(seg, minlength=12)
+    last = np.maximum(np.cumsum(count) - 1, 0)
+    args = (data, w, jnp.array(pos), jnp.array(last), jnp.array(count > 0))
     h1, t1, n1 = segmented_head_tail(*args, use_kernel=False)
     h2, t2, n2 = segmented_head_tail(*args, use_kernel=True)
     assert np.abs(np.asarray(t1) - np.asarray(t2)).max() < 1e-4
@@ -97,9 +99,10 @@ def test_head_tail_kernel_single_row_segments(rng):
     m, n = 16, 8
     data = jnp.array(rng.normal(size=(m, n)), jnp.float32)
     w = jnp.ones((m,), jnp.float32)
-    seg = jnp.arange(m, dtype=jnp.int32)
+    last = jnp.arange(m, dtype=jnp.int32)
     pos = jnp.zeros(m, jnp.int32)
-    h, t, norms = segmented_head_tail(data, w, seg, pos, m, use_kernel=True)
+    h, t, norms = segmented_head_tail(data, w, pos, last, jnp.ones(m, bool),
+                                      use_kernel=True)
     np.testing.assert_allclose(np.asarray(t), 0, atol=0)
     np.testing.assert_allclose(np.asarray(h), np.asarray(data), rtol=1e-6)
 
