@@ -65,7 +65,10 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.counts import (check_counts_exact, compute_counts_reference,
+                               largest_count)
 from repro.core.engine import FigaroEngine, default_engine, plan_for
+from repro.core.figaro import r0_nonzero_rows_bound
 from repro.core.join_tree import FigaroPlan, JoinTree, build_plan
 from repro.core.plan_cache import (PlanHolder, _append_rows, bucket_spec,
                                    build_capacity_plan, pad_data, pad_plan,
@@ -473,6 +476,7 @@ class JoinDataset:
         self._hysteresis = hysteresis
         self._replanner: Replanner | None = None
         self._warm_plans: dict[str, FigaroPlan] = {}
+        self._counted: tuple[FigaroPlan, dict] | None = None
 
     # -- plan lifecycle ------------------------------------------------------
 
@@ -629,13 +633,38 @@ class JoinDataset:
         if self._replanner is not None:
             self._replanner.on_reroot(root)
 
+    def _join_counts(self, plan: FigaroPlan) -> dict:
+        """The host's exact counts of ``plan`` (kept until the plan
+        changes): join size, R₀ rows and the largest per-key count."""
+        if self._counted is None or self._counted[0] is not plan:
+            counts = compute_counts_reference(plan)
+            self._counted = (plan, {
+                "join_rows": int(counts[plan.spec.root]["full"].sum()),
+                "r0_rows": plan.spec.r0_rows,
+                "r0_nonzero_rows_bound": r0_nonzero_rows_bound(plan),
+                "largest_count": largest_count(counts)})
+        return self._counted[1]
+
+    def _check_counts(self, kind: str, dtype) -> FigaroPlan:
+        """The plan, once its counts are known to be exact in the dtype
+        ``kind`` runs in (`counts.check_counts_exact`)."""
+        plan = self.plan
+        check_counts_exact(self._join_counts(plan)["largest_count"],
+                           self._session._dtype_for(kind, dtype))
+        return plan
+
     def stats(self) -> dict:
         """Lifecycle + compile counters: per-node capacity vs live rows,
         appends/regrows, and the session engine's per-kind trace counts,
         eviction counts, and cache size. A zero-retrace append shows up as
         ``traces`` staying flat across dispatches. Appends made through a
         live server (``server.append``) are counted here too — the dataset
-        and its servers share one plan holder."""
+        and its servers share one plan holder.
+
+        Once the plan is built, what the join adds: ``join_rows`` (the exact
+        join size, counted on the host in int64), ``r0_rows`` (R₀'s rows at
+        capacity) and ``r0_nonzero_rows_bound`` (those that can be non-zero,
+        `figaro.r0_nonzero_rows_bound`); None before."""
         engine = self._session.engine
         plan = self._holder.plan
         nodes = {}
@@ -649,8 +678,12 @@ class JoinDataset:
                 nodes[name] = {"capacity_rows": None,
                                "live_rows": self._tree.db[name].num_rows}
         appends, regrows = self._holder.counters()
+        joined = self._join_counts(plan) if plan is not None else {}
         return {
             "plan_built": plan is not None,
+            "join_rows": joined.get("join_rows"),
+            "r0_rows": joined.get("r0_rows"),
+            "r0_nonzero_rows_bound": joined.get("r0_nonzero_rows_bound"),
             "appends": appends,
             "regrows": regrows,
             "root": self.tree.root,
@@ -727,31 +760,34 @@ class JoinDataset:
         return pad_data(data, plan.spec)
 
     def r0(self, data=None, **overrides):
-        return self._session.r0(self.plan, self._request_data(data),
-                                **overrides)
+        plan = self._check_counts("r0", overrides.get("dtype"))
+        return self._session.r0(plan, self._request_data(data), **overrides)
 
     def qr(self, data=None, **overrides):
         """R of the join's QR; ``data`` with a leading batch axis serves the
         whole batch in one (mesh-sharded, when configured) dispatch."""
-        return self._session.qr(self.plan, self._request_data(data),
-                                **overrides)
+        plan = self._check_counts("qr", overrides.get("dtype"))
+        return self._session.qr(plan, self._request_data(data), **overrides)
 
     def svd(self, data=None, *, k: int | None = None, **overrides):
         """(s, Vᵀ) of the join matrix; ``k`` keeps the top-k."""
-        return self._session.svd(self.plan, self._request_data(data), k=k,
+        plan = self._check_counts("svd", overrides.get("dtype"))
+        return self._session.svd(plan, self._request_data(data), k=k,
                                  **overrides)
 
     def pca(self, data=None, *, k: int | None = None, center: bool = True,
             **overrides):
         """`PCAResult` (components, explained variance, factorized mean)."""
-        return self._session.pca(self.plan, self._request_data(data), k=k,
+        plan = self._check_counts("pca", overrides.get("dtype"))
+        return self._session.pca(plan, self._request_data(data), k=k,
                                  center=center, **overrides)
 
     def lsq(self, y, data=None, *, ridge: float = 0.0, **overrides):
         """Closed-form linear regression of label column ``y`` (index, bare
         name, or ``"Node.attr"``) against all other columns."""
+        plan = self._check_counts("least_squares", overrides.get("dtype"))
         return self._session.least_squares(
-            self.plan, self.column_index(y), self._request_data(data),
+            plan, self.column_index(y), self._request_data(data),
             ridge=ridge, **overrides)
 
     def serve(self, kind: str = "qr", *, label_col=None, **kw):
@@ -762,11 +798,14 @@ class JoinDataset:
         The server shares this dataset's plan *holder*: ``server.append``
         and ``ds.append`` refresh one plan state (draining the server's
         in-flight work first), so ``ds.plan`` / ``ds.stats()`` and the
-        served plan can never fork.
+        served plan can never fork. Like every compute method, it refuses a
+        dtype whose counts would not be exact (`counts.check_counts_exact`).
         """
+        validate_serve_kind(kind)
         if label_col is not None:
             label_col = self.column_index(label_col)
-        _ = self.plan  # build the capacity plan before sharing the holder
+        # Builds the capacity plan before the holder is shared.
+        self._check_counts(_SERVE_ENGINE_KINDS[kind], kw.get("dtype"))
         return self._session.serve(self._holder, kind=kind,
                                    label_col=label_col, **kw)
 

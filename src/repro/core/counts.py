@@ -16,7 +16,9 @@ computed in floating point of a configurable dtype; sqrt of the counts is what
 FiGaRo actually consumes. The default is float64: float32 is exact only up to
 2^24, beyond which the full-join sizes round and ``phi_circ`` (= full / rpk)
 silently corrupts the emission scaling. A numpy int64 reference lives in
-`compute_counts_reference` for exactness tests.
+`compute_counts_reference`, which also gives the façade its exact join size
+(`JoinDataset.stats`) and the largest count a request will meet
+(`check_counts_exact`).
 
 Capacity-padded (masked) plans — see `repro.core.plan_cache` — carry group
 slots with ``group_count == 0``; their counts are identically zero, and every
@@ -32,7 +34,8 @@ import numpy as np
 
 from .join_tree import FigaroPlan
 
-__all__ = ["NodeCounts", "compute_counts", "compute_counts_reference"]
+__all__ = ["NodeCounts", "compute_counts", "compute_counts_reference",
+           "largest_count", "check_counts_exact"]
 
 
 class NodeCounts(dict):
@@ -89,8 +92,19 @@ def compute_counts(plan: FigaroPlan, dtype=jnp.float64) -> list[NodeCounts]:
     return out
 
 
+def _exact_div(num: np.ndarray, den: np.ndarray) -> np.ndarray:
+    """``num // den`` in int64, asserted exact; 0 where ``den`` is 0 (dead
+    capacity slots of masked plans)."""
+    live = den > 0
+    assert np.all(num[live] % den[live] == 0)
+    out = np.zeros_like(num)
+    out[live] = num[live] // den[live]
+    return out
+
+
 def compute_counts_reference(plan: FigaroPlan) -> list[dict[str, np.ndarray]]:
-    """Same two-pass recurrences in numpy int64 (exact) — test oracle."""
+    """Same two-pass recurrences in numpy int64 (exact) — test oracle, and
+    the host's exact counts. Capacity-padded plans give their dead slots 0."""
     nodes = plan.nodes
     out: list[dict[str, np.ndarray]] = [dict() for _ in nodes]
     for idx in reversed(plan.preorder):
@@ -112,11 +126,31 @@ def compute_counts_reference(plan: FigaroPlan) -> list[dict[str, np.ndarray]]:
         else:
             full = out[idx]["theta_down"]
         out[idx]["full"] = full
-        assert np.all(full % out[idx]["rpk"] == 0)
-        out[idx]["phi_circ"] = full // out[idx]["rpk"]
+        out[idx]["phi_circ"] = _exact_div(full, out[idx]["rpk"])
         for ch in nd.children:
             acc = np.zeros(nodes[ch].P, dtype=np.int64)
             np.add.at(acc, nd.child_lookup[ch], full)
-            assert np.all(acc % out[ch]["phi_down"] == 0)
-            out[ch]["phi_up"] = acc // out[ch]["phi_down"]
+            out[ch]["phi_up"] = _exact_div(acc, out[ch]["phi_down"])
     return out
+
+
+def largest_count(counts: list[dict[str, np.ndarray]]) -> int:
+    """The largest per-key count Algorithm 1 forms: every other count is a
+    part of some node's ``full`` (the join rows a key takes part in)."""
+    return max(int(c["full"].max(initial=0)) for c in counts)
+
+
+def check_counts_exact(largest: int, dtype) -> None:
+    """Refuse to run Algorithm 1 in ``dtype`` where a count passes the
+    largest integer it holds exactly (2^24 in float32), which would corrupt
+    the √Φ scalings without a sign. Types narrower than float32 (exact to
+    256 or 2,048) only serve as precision controls and are not checked."""
+    info = jnp.finfo(dtype)
+    if info.bits < 32:
+        return
+    limit = 2 ** (info.nmant + 1)
+    if largest > limit:
+        raise ValueError(
+            f"a join key takes part in {largest:,} join rows, more than "
+            f"{jnp.dtype(dtype).name} counts exactly ({limit:,}); serve in "
+            f"float64")

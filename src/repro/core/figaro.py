@@ -54,12 +54,12 @@ so masked slab rows are exactly zero straight out of the kernel.
 
 Each phase of the traced body runs under a `jax.named_scope`, so a device op's
 name stack (the ``tf_op`` a profiler trace carries) says which phase it belongs
-to: ``figaro.counts`` (Algorithm 1), ``figaro.heads_tails`` (lines 11-16,
-with the node's name as a sub-scope), ``figaro.join_children`` (lines 17-26),
-``figaro.project`` (lines 27-34) and ``figaro.assemble``; the post-processing
-(``figaro.postprocess``) and the downstream reads (``figaro.downstream``) are
-scoped where they are traced. Scopes are metadata only: the compiled program
-is op for op the same.
+to: ``figaro.counts`` (Algorithm 1), ``figaro.heads_tails`` (lines 11-16),
+``figaro.join_children`` (lines 17-26) and ``figaro.project`` (lines 27-34),
+each with the node's name as a sub-scope, and ``figaro.assemble``; the
+post-processing (``figaro.postprocess``) and the downstream reads
+(``figaro.downstream``) are scoped where they are traced. Scopes are metadata
+only: the compiled program is op for op the same.
 """
 
 from __future__ import annotations
@@ -69,13 +69,14 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .counts import compute_counts
 from .heads_tails import segmented_head_tail
 from .join_tree import FigaroPlan, PlanSpec
 
 __all__ = ["figaro_r0", "figaro_r0_batched", "figaro_r0_fn",
-           "assembly_traffic"]
+           "assembly_traffic", "r0_nonzero_rows_bound"]
 
 ASSEMBLIES = ("padded", "band")
 
@@ -136,6 +137,30 @@ def assembly_traffic(spec: PlanSpec, *, assembly: str = "padded",
         band_writes = sum(b.rows * b.width for b in spec.bands)
         return (full + band_writes) * itemsize
     raise ValueError(f"unknown assembly {assembly!r}; expected {ASSEMBLIES}")
+
+
+def r0_nonzero_rows_bound(plan: FigaroPlan) -> int:
+    """How many of R₀'s rows can be non-zero, from the plan's live structure.
+
+    A group's first row has an empty prefix, so its tail is zero, and so is
+    the generalized tail of a parent group's first group; dead capacity rows
+    are zero, and so is every row of a slab with no column. What is left:
+    per node, its live rows past each group's first (if it has a column),
+    its live groups past each parent group's first (if its subtree has a
+    column), and at the root every live group's head row.
+    """
+    rows = 0
+    for sp, ix in zip(plan.spec.nodes, plan.index):
+        live_rows = (int(np.sum(ix.row_mask)) if ix.row_mask is not None
+                     else sp.m)
+        groups = int(np.count_nonzero(ix.group_count))
+        if sp.n:
+            rows += live_rows - groups
+        if sp.parent < 0:
+            rows += groups
+        elif sp.subtree_width:
+            rows += groups - int(np.count_nonzero(ix.pgroup_count))
+    return rows
 
 
 def figaro_r0(
@@ -214,7 +239,8 @@ def figaro_r0(
                 tail_slabs[idx] = tails * jnp.sqrt(phi_circ_row)[:, None]
 
         # --- PROCESS_AND_JOIN_CHILDREN (lines 17-26) ----------------------
-        with jax.named_scope("figaro.join_children"):
+        with (jax.named_scope("figaro.join_children"),
+              jax.named_scope(sp.name)):
             scales = jnp.sqrt(cnt["rpk"])  # √|S_i^x̄|, one per key
             if sp.children:
                 # (data [K, w_ch], scale [K]) in child (column) order
@@ -241,7 +267,8 @@ def figaro_r0(
                 data_mat = heads  # width == n for a leaf
 
         # --- PROJECT_AWAY_JOIN_ATTRIBUTES (lines 27-34) / root (lines 7-8) -
-        with jax.named_scope("figaro.project"):
+        with (jax.named_scope("figaro.project"),
+              jax.named_scope(sp.name)):
             if sp.parent >= 0:
                 group_to_pgroup = jnp.asarray(ix.group_to_pgroup)
                 pos_in_pgroup = jnp.asarray(ix.pos_in_pgroup)

@@ -89,6 +89,28 @@ def test_counts_default_dtype_exact_above_2pow24():
     assert int(np.asarray(c32[root]["full"]).sum()) != full
 
 
+def test_a_float32_request_past_2pow24_is_refused():
+    """The façade runs Algorithm 1 in the served dtype; where a key's count
+    passes 2^24 a float32 request would be answered with corrupt scalings,
+    so it is refused before anything is traced, naming float64."""
+    from repro import figaro
+    from repro.core.counts import check_counts_exact, largest_count
+    from repro.data.relational import cartesian as cartesian_tree
+
+    ds = figaro.Session().from_tree(cartesian_tree(5001, 3355, n1=1, n2=1,
+                                                   seed=0))
+    with pytest.raises(ValueError, match="float64"):
+        ds.qr()
+    with pytest.raises(ValueError, match="float64"):
+        ds.serve(kind="qr", dtype=jnp.float32)
+    largest = largest_count(compute_counts_reference(ds.plan))
+    assert largest == ds.stats()["join_rows"] == 5001 * 3355
+    check_counts_exact(largest, jnp.float64)
+    check_counts_exact(2 ** 24, jnp.float32)  # the last exact count
+    check_counts_exact(largest, jnp.bfloat16)  # a control: not checked
+    assert ds.stats()["traces"] == {}
+
+
 @settings(max_examples=25, deadline=None)
 @given(topology=st.sampled_from(list(TOPOLOGIES)), seed=st.integers(0, 2**31),
        cartesian=st.booleans())

@@ -421,6 +421,11 @@ def test_every_phase_scope_reaches_the_op_metadata(rng, kind):
                    for n in names), scope
     node = plan.spec.nodes[plan.spec.root].name
     assert any(f"figaro.heads_tails)/{node}/" in n for n in names), node
+    # The node passes name their node too, so a trace can tell the gathers
+    # at the root from those further down.
+    assert any(f"figaro.join_children)/{node}/" in n for n in names), node
+    child = plan.spec.nodes[plan.spec.nodes[plan.spec.root].children[0]].name
+    assert any(f"figaro.project)/{child}/" in n for n in names), child
 
 
 def test_phase_scopes_leave_the_compiled_program_unchanged(rng, monkeypatch):
