@@ -11,7 +11,9 @@ binary combine tree). Here:
   * `tsqr_r`             — row-blocked leaf QRs + log₂ pairwise combine
                            (THIN on TPU; the mesh version lives in
                            `core/distributed.py`).
-  * `postprocess_r0`     — R₀ → upper-triangular R with non-negative diagonal.
+  * `postprocess_r0`     — R₀ → upper-triangular R with non-negative diagonal;
+                           float32 TSQR on a TPU runs every level on the
+                           Pallas `tsqr_leaf` kernel (`kernels/tsqr_leaf`).
 
 All functions return only R (the paper never materializes Q either).
 """
@@ -22,6 +24,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import _platform
 
 __all__ = [
     "householder_qr_r",
@@ -165,7 +169,8 @@ def tsqr_r(a: jnp.ndarray, leaf_rows: int = 256,
     """TSQR: row-block leaf QRs, then pairwise combines — THIN (§7) in block form.
 
     [m, n] -> R [n, n]. Rows are zero-padded to a full grid; zero rows do not
-    change R.
+    change R. The two levels run under the ``leaves`` and ``combine`` named
+    scopes.
     """
     m, n = a.shape
     leaf_rows = max(leaf_rows, n)
@@ -173,24 +178,44 @@ def tsqr_r(a: jnp.ndarray, leaf_rows: int = 256,
     pad = blocks * leaf_rows - m
     if pad:
         a = jnp.concatenate([a, jnp.zeros((pad, n), a.dtype)], axis=0)
-    rs = jax.vmap(leaf_qr)(a.reshape(blocks, leaf_rows, n))  # [B, n, n]
-    while rs.shape[0] > 1:
-        b = rs.shape[0]
-        if b % 2:
-            rs = jnp.concatenate([rs, jnp.zeros((1, n, n), a.dtype)], axis=0)
-            b += 1
-        stacked = rs.reshape(b // 2, 2 * n, n)
-        rs = jax.vmap(leaf_qr)(stacked)
+    with jax.named_scope("leaves"):
+        rs = jax.vmap(leaf_qr)(a.reshape(blocks, leaf_rows, n))  # [B, n, n]
+    with jax.named_scope("combine"):
+        while rs.shape[0] > 1:
+            b = rs.shape[0]
+            if b % 2:
+                rs = jnp.concatenate([rs, jnp.zeros((1, n, n), a.dtype)],
+                                     axis=0)
+                b += 1
+            stacked = rs.reshape(b // 2, 2 * n, n)
+            rs = jax.vmap(leaf_qr)(stacked)
     return rs[0]
+
+
+def _tsqr_on_kernel(r0: jnp.ndarray, leaf_rows: int) -> bool:
+    """Whether TSQR runs on the `tsqr_leaf` kernel: float32 on a TPU, with a
+    leaf (and a pair of Rs) that fits the kernel's VMEM."""
+    from repro.kernels.tsqr_leaf import kernel as tl_kernel
+    n = r0.shape[1]
+    return (r0.dtype == jnp.float32 and _platform.on_tpu()
+            and tl_kernel.fits(n, max(leaf_rows, 2 * n)))
 
 
 def postprocess_r0(r0: jnp.ndarray, *, method: str = "tsqr",
                    leaf_rows: int = 256, panel: int = 32,
                    use_kernel: bool = False) -> jnp.ndarray:
     """R₀ (M×N, almost upper-triangular) → R (N×N, diag ≥ 0), under the
-    ``figaro.postprocess`` named scope."""
+    ``figaro.postprocess`` named scope.
+
+    ``method="tsqr"`` in float32 on a TPU runs on the `tsqr_leaf` kernel
+    whatever ``use_kernel`` says; elsewhere its leaves are `householder_qr_r`,
+    or blocked WY with the `panel_qr` kernel under ``use_kernel``.
+    """
     with jax.named_scope("figaro.postprocess"):
-        if method == "tsqr":
+        if method == "tsqr" and _tsqr_on_kernel(r0, leaf_rows):
+            from repro.kernels.tsqr_leaf import ops as tl_ops
+            r = tl_ops.tsqr_r(r0, leaf_rows=leaf_rows)
+        elif method == "tsqr":
             leaf = functools.partial(blocked_qr_r, panel=panel,
                                      use_kernel=use_kernel) \
                 if use_kernel else householder_qr_r

@@ -2,7 +2,9 @@
 
   node_fused/  fused per-node FiGaRo pass (mask·head/tail·φ·emit) — hot path
   head_tail/   segmented generalized head/tail — the unfused building block
-  panel_qr/    Householder panel factorization — post-processing hot spot
+  tsqr_leaf/   Householder R of a stack of TSQR leaves in VMEM — float32
+               post-processing on a TPU, every TSQR level
+  panel_qr/    Householder panel factorization — blocked QR under use_kernel
   flash_attn/  fused GQA attention — serving-side mixer hot spot
 
 Platform policy (compiled on TPU/GPU, interpreted elsewhere, explicit

@@ -399,13 +399,13 @@ ALGORITHM_2 = ("figaro.counts", "figaro.heads_tails", "figaro.join_children",
 KIND_OPTIONS = {"qr": {}, "pca": {"k": 2, "center": True}}
 
 
-def _compiled_hlo(kind, plan, data):
+def _compiled_hlo(kind, plan, data, leaf_rows=256):
     """Optimized HLO of the batched ``kind`` program as the engine jits it."""
     engine = FigaroEngine(donate_data=False)
     impl = getattr(engine, f"_{kind}_batched_impl")
     options = dict(KIND_OPTIONS[kind], dtype=np.dtype(np.float64),
-                   method="tsqr", leaf_rows=256, panel=32, use_kernel=False,
-                   assembly="padded")
+                   method="tsqr", leaf_rows=leaf_rows, panel=32,
+                   use_kernel=False, assembly="padded")
     fn = jax.jit(lambda p, d: impl(p, d, **options))
     return fn.lower(plan.without_data(), data).compile().as_text()
 
@@ -426,6 +426,18 @@ def test_every_phase_scope_reaches_the_op_metadata(rng, kind):
     assert any(f"figaro.join_children)/{node}/" in n for n in names), node
     child = plan.spec.nodes[plan.spec.nodes[plan.spec.root].children[0]].name
     assert any(f"figaro.project)/{child}/" in n for n in names), child
+
+
+@pytest.mark.parametrize("kind", list(KIND_OPTIONS))
+def test_tsqr_levels_reach_the_op_metadata(rng, kind):
+    """TSQR's leaves and its combine levels carry their own sub-scopes of
+    ``figaro.postprocess``, so a trace can tell one from the other."""
+    _, plan = _plan("star", rng)
+    hlo = _compiled_hlo(kind, plan, _batch(plan, rng, 2, np.float64),
+                        leaf_rows=8)
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for level in ("leaves", "combine"):
+        assert any(f"figaro.postprocess)/{level}/" in n for n in names), level
 
 
 def test_phase_scopes_leave_the_compiled_program_unchanged(rng, monkeypatch):

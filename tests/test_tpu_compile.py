@@ -13,6 +13,7 @@ imports every test file.
 
 import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from repro.data.relational import yelp_like
 from repro.kernels import _platform
 from repro.kernels.node_fused.kernel import node_fused_kernel
 from repro.kernels.panel_qr.kernel import panel_qr_kernel
+from repro.kernels.tsqr_leaf import ops as tsqr_leaf_ops
 
 from helpers import hlo_instructions
 
@@ -77,12 +79,41 @@ def test_panel_qr_compiles_at_tsqr_leaf(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# R0 of the benchmark's cells: yelp_reviews (14,336 leaves of 256 rows, N =
+# 29) and favorita_sales (33,889 leaves, the last one partial, N = 8).
+@pytest.mark.parametrize("m, n", [(14336 * 256, 29), (8675456, 8)])
+def test_tsqr_leaf_compiles_at_full_size(one_chip, m, n):
+    """Every TSQR level on the kernel: the leaves and each combine level."""
+    tsqr = jax.jit(tsqr_leaf_ops.tsqr_r,
+                   static_argnames=("leaf_rows", "interpret"))
+    compiled = tsqr.lower(_spec((m, n), jnp.float32, one_chip),
+                          leaf_rows=256, interpret=False).compile()
+    leaves = -(-m // 256)
+    levels = 1 + int(np.ceil(np.log2(leaves)))
+    assert _kernel_calls(compiled.as_text()) == ["tsqr_leaf"] * levels
+
+
+def _kernel_calls(text):
+    """The name of every Pallas kernel call in an optimized HLO text."""
+    return re.findall(r'%([a-z_]+)\.\d+ = [^\n]*custom_call_target='
+                      r'"tpu_custom_call"', text)
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_qr_engine_program_compiles(one_chip, tpu_backend, use_kernel):
     """The whole batched qr program the async server dispatches (B=4,
-    float32), for a small capacity plan."""
-    compiled = _qr_program(one_chip, use_kernel)
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+    float32), for a small capacity plan: TSQR runs on the `tsqr_leaf` kernel
+    whatever ``use_kernel`` says, and the fused node passes only under it."""
+    calls = set(_kernel_calls(_qr_program(one_chip, use_kernel).as_text()))
+    assert calls == ({"tsqr_leaf", "node_fused_kernel"} if use_kernel
+                     else {"tsqr_leaf"})
+
+
+def test_float64_svd_program_has_no_tsqr_leaf(one_chip, tpu_backend):
+    """float64 keeps the XLA loop: the svd program lowered for the chip
+    carries no kernel call at all."""
+    text = _lowered(one_chip, "svd_batched", np.float64).as_text()
+    assert "tsqr_leaf" not in text and "tpu_custom_call" not in text
 
 
 def test_phase_scopes_leave_the_tpu_program_unchanged(one_chip, tpu_backend,
@@ -99,17 +130,20 @@ def test_phase_scopes_leave_the_tpu_program_unchanged(one_chip, tpu_backend,
 
 
 def _qr_program(one_chip, use_kernel):
+    return _lowered(one_chip, "qr_batched", np.float32,
+                    use_kernel=use_kernel).compile()
+
+
+def _lowered(one_chip, kind, dtype, *, use_kernel=False):
     plan = build_capacity_plan(yelp_like())
     as_spec = lambda x: _spec(np.shape(x), np.asarray(x).dtype, one_chip)
     plan_spec = jax.tree.map(as_spec, plan.without_data())
     data_spec = tuple(_spec((4,) + np.shape(d), np.float64, one_chip)
                       for d in plan.data)
-    program = FigaroEngine()._make_jitted("qr_batched", False, None, None,
-                                          None)
+    program = FigaroEngine()._make_jitted(kind, False, None, None, None)
     return program.lower(
-        plan_spec, data_spec, dtype=np.dtype(np.float32), method="tsqr",
-        leaf_rows=256, panel=32, use_kernel=use_kernel,
-        assembly="padded").compile()
+        plan_spec, data_spec, dtype=np.dtype(dtype), method="tsqr",
+        leaf_rows=256, panel=32, use_kernel=use_kernel, assembly="padded")
 
 
 @pytest.mark.parametrize("kernel", ["node_fused", "panel_qr"])
