@@ -1,5 +1,5 @@
-"""Kernel-layer benchmark: FiGaRo inner loop (segmented head/tail), the fused
-node kernel, and the post-processing panel QR.
+"""Kernel-layer benchmark: FiGaRo inner loop (segmented head/tail) and the
+fused node kernel.
 
 On this CPU container the Pallas kernels execute in ``interpret=True`` mode
 (Python emulation — NOT indicative of TPU speed); wall time is reported for
@@ -17,9 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.heads_tails import segmented_head_tail
-from repro.core.postprocess import blocked_qr_r
 from repro.kernels.node_fused import fused_node_pass, fused_node_pass_ref
-from repro.kernels.panel_qr import ops as pq_ops, ref as pq_ref
 
 from ._util import Csv, timeit, write_bench_json
 
@@ -77,15 +75,6 @@ def run(csv: Csv, *, fast: bool = False) -> None:
             s2, h2, nn2 = fused_node_pass(*f_args)
             add(case, "kernel_slab_max_abs_err", float(jnp.abs(s1 - s2).max()))
             add(case, "kernel_head_max_abs_err", float(jnp.abs(h1 - h2).max()))
-
-    for m, nb in [(512, 64)] if fast else [(512, 64), (2048, 128)]:
-        a = jnp.array(rng.normal(size=(m, nb)), jnp.float32)
-        t = timeit(lambda: blocked_qr_r(a, panel=32))
-        add(f"panelqr_{m}x{nb}", "xla_path_s", t)
-        v1, b1, r1 = pq_ops.panel_qr(a[:, :32])
-        v2, b2, r2 = pq_ref.panel_qr_ref(a[:, :32])
-        add(f"panelqr_{m}x{nb}", "kernel_max_abs_err",
-            float(jnp.abs(r1 - r2).max()))
 
     write_bench_json("kernels", rows)
 

@@ -207,9 +207,9 @@ print("OK — Session(use_kernel=, assembly=) select the accelerated paths.")
 # --- 9. running figaro-lint: the invariants above, machine-checked ----------
 # Everything this example leaned on is a structural invariant nothing at
 # runtime enforces: version-sensitive JAX spellings live only in
-# repro/compat.py (FIG001); the engine's _STATIC table matches each impl's
-# keyword-only options and plans pass THROUGH jit, never closed over
-# (FIG002 — the zero-retrace story of steps 4-7); core/ and kernels/ derive
+# repro/compat.py (FIG001); jit's static_argnames name real parameters
+# and plans pass THROUGH jit, never closed over (FIG002 — the zero-retrace
+# story of steps 4-7); core/ and kernels/ derive
 # dtypes from inputs instead of hardcoding float32 (FIG003); every
 # pallas_call routes interpret= through kernels/_platform.resolve_interpret
 # and grids divide ceil-padded dims (FIG004 — step 8's kernels); the async

@@ -13,10 +13,9 @@ the next violation before a human has to:
   FIG001  compat-pin        version-sensitive JAX symbols (shard_map,
                             make_mesh, AxisType, AbstractMesh, axis_size)
                             imported anywhere outside repro/compat.py
-  FIG002  retrace-hazard    `_STATIC` dispatch-flag sets drifting out of
-                            sync with impl keyword lists, static_argnames
-                            naming non-parameters or unhashable defaults,
-                            jitted closures capturing plan objects
+  FIG002  retrace-hazard    static_argnames naming non-parameters or
+                            unhashable defaults, jitted closures capturing
+                            plan objects
   FIG003  dtype-drift       hardcoded narrowing dtype literals in core/ and
                             kernels/ bodies (the I/O-dtype policy derives
                             from inputs), count accumulation narrower

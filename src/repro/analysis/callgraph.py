@@ -36,7 +36,7 @@ from .framework import FileContext
 from .imports import ImportGraph
 
 #: Engine dispatch-impl methods are jit roots by contract: `_make_jitted`
-#: wraps `_<kind>_impl` in `jax.jit` with the kind's `_STATIC` kwonly names.
+#: wraps `_<kind>_impl` in `jax.jit` with its kwonly names static.
 _IMPL_RE = re.compile(r"^_\w+_impl$")
 
 #: Lock factories (mirrors rules/lock_discipline._LOCK_FACTORIES without the
@@ -369,8 +369,8 @@ class CallGraph:
             mod = self.modules[fi.module]
             if fi.cls is not None and _IMPL_RE.match(fi.name) \
                     and fi.qname not in self.roots:
-                # Engine contract: every kwonly arg of an impl is a _STATIC
-                # dispatch flag, hashable and concrete at trace time.
+                # Engine contract: every kwonly arg of an impl is a static
+                # dispatch option, hashable and concrete at trace time.
                 self.roots[fi.qname] = Root(fi.qname, "engine-impl",
                                             frozenset(fi.kwonly()))
             for dec in fi.node.decorator_list:

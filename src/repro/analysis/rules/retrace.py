@@ -1,12 +1,8 @@
 """FIG002 — retrace hazards around `jax.jit` dispatch signatures.
 
-Zero-retrace serving rests on three structural facts that nothing at runtime
+Zero-retrace serving rests on two structural facts that nothing at runtime
 enforces until a trace-counter test happens to cover the broken path:
 
-  * the engine's ``_STATIC`` table (kind -> static_argnames) must list
-    exactly the keyword-only options of the matching ``_<kind>_impl`` body —
-    a drifted entry either retraces per call (option became a traced value)
-    or crashes on an unknown static name;
   * ``static_argnames`` handed to `jax.jit` must name real parameters of the
     jitted callable, and a parameter marked static must not default to an
     unhashable literal (list/dict/set) — both fail only at first dispatch;
@@ -57,7 +53,7 @@ def _static_argnames(node: ast.Call) -> tuple[ast.keyword | None, list[str]]:
                     kw.value.value, str):
                 names.append(kw.value.value)
             else:
-                return kw, []  # computed (e.g. self._STATIC[kind]): skip
+                return kw, []  # computed at run time: not checkable
             return kw, names
     return None, []
 
@@ -65,10 +61,6 @@ def _static_argnames(node: ast.Call) -> tuple[ast.keyword | None, list[str]]:
 def _param_names(fn: ast.FunctionDef) -> set[str]:
     a = fn.args
     return {p.arg for p in (a.posonlyargs + a.args + a.kwonlyargs)}
-
-
-def _kwonly_names(fn: ast.FunctionDef) -> set[str]:
-    return {p.arg for p in fn.args.kwonlyargs}
 
 
 def _unhashable_defaults(fn: ast.FunctionDef, static: set[str]) -> list[str]:
@@ -148,64 +140,11 @@ def _planish_names(fn: ast.FunctionDef, ctx: FileContext) -> set[str]:
 class RetraceHazardRule(Rule):
     rule_id = "FIG002"
     severity = Severity.ERROR
-    fix_hint = ("pass plans through jit as pytree arguments and keep "
-                "static_argnames == the impl's keyword-only options "
-                "(see core/engine.py:_STATIC)")
+    fix_hint = ("pass plans through jit as pytree arguments and name only "
+                "real, hashable parameters in static_argnames")
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:
-        yield from self._check_static_table(ctx)
         yield from self._check_jit_calls(ctx)
-
-    # -- _STATIC <-> impl keyword sync --------------------------------------
-
-    def _check_static_table(self, ctx: FileContext) -> Iterator[Finding]:
-        for cls in ast.walk(ctx.tree):
-            if not isinstance(cls, ast.ClassDef):
-                continue
-            table = None
-            impls: dict[str, ast.FunctionDef] = {}
-            for stmt in cls.body:
-                if (isinstance(stmt, ast.Assign)
-                        and any(isinstance(t, ast.Name) and t.id == "_STATIC"
-                                for t in stmt.targets)
-                        and isinstance(stmt.value, ast.Dict)):
-                    table = stmt.value
-                elif isinstance(stmt, ast.FunctionDef) and \
-                        stmt.name.endswith("_impl") and \
-                        stmt.name.startswith("_"):
-                    impls[stmt.name[1:-len("_impl")]] = stmt
-            if table is None or not impls:
-                continue
-            for key, value in zip(table.keys, table.values):
-                if not (isinstance(key, ast.Constant)
-                        and isinstance(key.value, str)):
-                    continue
-                kind = key.value
-                declared = set()
-                if isinstance(value, (ast.Tuple, ast.List)):
-                    declared = {e.value for e in value.elts
-                                if isinstance(e, ast.Constant)}
-                impl = impls.get(kind)
-                if impl is None:
-                    yield self.finding(
-                        ctx, key,
-                        f"_STATIC lists kind {kind!r} but "
-                        f"{cls.name} has no _{kind}_impl method")
-                    continue
-                actual = _kwonly_names(impl)
-                missing = sorted(actual - declared)
-                extra = sorted(declared - actual)
-                if missing:
-                    yield self.finding(
-                        ctx, key,
-                        f"_STATIC[{kind!r}] is missing impl keyword(s) "
-                        f"{missing} — they would dispatch as traced values "
-                        f"and retrace per call")
-                if extra:
-                    yield self.finding(
-                        ctx, key,
-                        f"_STATIC[{kind!r}] names {extra} which "
-                        f"_{kind}_impl does not accept")
 
     # -- jit call sites ------------------------------------------------------
 

@@ -132,13 +132,15 @@ class Session:
                  every append regrows the plan (one retrace each).
     headroom:    extra row capacity per node reserved at plan build, so a
                  known append rate cannot immediately overflow a bucket.
-    method, leaf_rows, panel, use_kernel, assembly:
+    leaf_rows, use_kernel, assembly:
                  pipeline defaults forwarded to every dispatch:
-                 ``use_kernel=True`` routes each join-tree node through the
-                 fused Pallas pass (`repro.kernels.node_fused`; compiled on
-                 TPU/GPU, interpreted on CPU), ``assembly`` ("padded" |
+                 ``leaf_rows`` is the row count of post-processing's TSQR
+                 leaves (`repro.core.postprocess`), ``use_kernel=True``
+                 routes each join-tree node through the fused Pallas pass
+                 (`repro.kernels.node_fused`; compiled on TPU/GPU,
+                 interpreted on CPU), ``assembly`` ("padded" |
                  "band") picks the R₀ materialization (`repro.core.figaro`).
-                 Both are static options — part of the executable cache key.
+                 All are static options — part of the executable cache key.
     donate_data, max_cached:
                  forwarded to the engine constructor; combining either with
                  ``engine=`` raises (configure the engine directly instead).
@@ -170,8 +172,7 @@ class Session:
 
     def __init__(self, *, engine: FigaroEngine | None = None, mesh=None,
                  shard_axis: str = "data", dtype=None, bucket: bool = True,
-                 headroom: int = 0, method: str = "tsqr",
-                 leaf_rows: int = 256, panel: int = 32,
+                 headroom: int = 0, leaf_rows: int = 256,
                  use_kernel: bool = False, assembly: str = "padded",
                  donate_data: bool | None = None,
                  max_cached: int | None = None):
@@ -186,9 +187,7 @@ class Session:
         self.dtype = dtype
         self.bucket = bucket
         self.headroom = headroom
-        self.method = method
         self.leaf_rows = leaf_rows
-        self.panel = panel
         self.use_kernel = use_kernel
         self.assembly = assembly
 
@@ -225,13 +224,11 @@ class Session:
             return self.dtype
         return _KIND_DTYPES[kind]
 
-    def _post_opts(self, kind: str, dtype, method, leaf_rows, panel,
-                   use_kernel, assembly) -> dict:
+    def _post_opts(self, kind: str, dtype, leaf_rows, use_kernel,
+                   assembly) -> dict:
         return dict(
             dtype=self._dtype_for(kind, dtype),
-            method=self.method if method is None else method,
             leaf_rows=self.leaf_rows if leaf_rows is None else leaf_rows,
-            panel=self.panel if panel is None else panel,
             use_kernel=self.use_kernel if use_kernel is None else use_kernel,
             assembly=self.assembly if assembly is None else assembly)
 
@@ -271,23 +268,21 @@ class Session:
             **self._dispatch_opts(data, batched, shard, bucket))
 
     def qr(self, tree_or_plan, data=None, *, batched=None, shard=_UNSET,
-           bucket=None, dtype=None, method=None, leaf_rows=None, panel=None,
-           use_kernel=None, assembly=None):
+           bucket=None, dtype=None, leaf_rows=None, use_kernel=None,
+           assembly=None):
         """Upper-triangular R of the join's QR ([B, N, N] when batched)."""
         return self.engine.qr(
             plan_for(tree_or_plan), data,
-            **self._post_opts("qr", dtype, method, leaf_rows, panel,
-                              use_kernel, assembly),
+            **self._post_opts("qr", dtype, leaf_rows, use_kernel, assembly),
             **self._dispatch_opts(data, batched, shard, bucket))
 
     def svd(self, tree_or_plan, data=None, *, k: int | None = None,
-            batched=None, shard=_UNSET, bucket=None, dtype=None, method=None,
-            leaf_rows=None, panel=None, use_kernel=None, assembly=None):
+            batched=None, shard=_UNSET, bucket=None, dtype=None,
+            leaf_rows=None, use_kernel=None, assembly=None):
         """Singular values + right-singular vectors; ``k`` keeps the top-k."""
         s, vt = self.engine.svd(
             plan_for(tree_or_plan), data,
-            **self._post_opts("svd", dtype, method, leaf_rows, panel,
-                              use_kernel, assembly),
+            **self._post_opts("svd", dtype, leaf_rows, use_kernel, assembly),
             **self._dispatch_opts(data, batched, shard, bucket))
         if k is not None:
             s, vt = s[..., :k], vt[..., :k, :]
@@ -295,30 +290,28 @@ class Session:
 
     def pca(self, tree_or_plan, data=None, *, k: int | None = None,
             center: bool = True, batched=None, shard=_UNSET, bucket=None,
-            dtype=None, method=None, leaf_rows=None, panel=None,
-            use_kernel=None, assembly=None):
+            dtype=None, leaf_rows=None, use_kernel=None, assembly=None):
         """PCA of the join matrix from R (+ factorized means)."""
         return self.engine.pca(
             plan_for(tree_or_plan), data, k=k, center=center,
-            **self._post_opts("pca", dtype, method, leaf_rows, panel,
-                              use_kernel, assembly),
+            **self._post_opts("pca", dtype, leaf_rows, use_kernel, assembly),
             **self._dispatch_opts(data, batched, shard, bucket))
 
     def least_squares(self, tree_or_plan, label_col: int, data=None, *,
                       ridge: float = 0.0, batched=None, shard=_UNSET,
-                      bucket=None, dtype=None, method=None, leaf_rows=None,
-                      panel=None, use_kernel=None, assembly=None):
+                      bucket=None, dtype=None, leaf_rows=None,
+                      use_kernel=None, assembly=None):
         """argmin_β ‖A[:, feats]·β − A[:, label]‖² over the join."""
         return self.engine.least_squares(
             plan_for(tree_or_plan), label_col, data, ridge=ridge,
-            **self._post_opts("least_squares", dtype, method, leaf_rows,
-                              panel, use_kernel, assembly),
+            **self._post_opts("least_squares", dtype, leaf_rows, use_kernel,
+                              assembly),
             **self._dispatch_opts(data, batched, shard, bucket))
 
     def serve(self, tree_or_plan, *, kind: str = "qr", label_col=None,
-              k=None, ridge: float = 0.0, dtype=None, method=None,
-              leaf_rows=None, use_kernel=None, assembly=None, mesh=_UNSET,
-              shard_axis=None, max_batch: int = 32, queue_depth: int = 2):
+              k=None, ridge: float = 0.0, dtype=None, leaf_rows=None,
+              use_kernel=None, assembly=None, mesh=_UNSET, shard_axis=None,
+              max_batch: int = 32, queue_depth: int = 2):
         """An async pipelined serving endpoint for one join structure (see
         `train.serve.make_figaro_server`): ``submit(request)`` returns a
         `FigaroFuture`, pending requests coalesce up to ``max_batch`` rows,
@@ -336,7 +329,6 @@ class Session:
             target, kind=kind, label_col=label_col, k=k,
             ridge=ridge, engine=self.engine,
             dtype=self._dtype_for(_SERVE_ENGINE_KINDS[kind], dtype),
-            method=self.method if method is None else method,
             leaf_rows=self.leaf_rows if leaf_rows is None else leaf_rows,
             use_kernel=self.use_kernel if use_kernel is None else use_kernel,
             assembly=self.assembly if assembly is None else assembly,
@@ -345,8 +337,7 @@ class Session:
             max_batch=max_batch, queue_depth=queue_depth)
 
     def partitioned_qr(self, tree: JoinTree, num_parts: int, *, mesh=_UNSET,
-                       dtype=None, method=None, use_kernel=None,
-                       assembly=None):
+                       dtype=None, use_kernel=None, assembly=None):
         """Fact-partitioned multi-device QR (`distributed` layer) through
         this session's engine/mesh."""
         from repro.core.distributed import partitioned_figaro_qr
@@ -356,7 +347,6 @@ class Session:
             mesh=self.mesh if mesh is _UNSET else mesh,
             dtype=(dtype if dtype is not None else
                    self.dtype if self.dtype is not None else jnp.float64),
-            method=self.method if method is None else method,
             use_kernel=self.use_kernel if use_kernel is None else use_kernel,
             assembly=self.assembly if assembly is None else assembly)
 

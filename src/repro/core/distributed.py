@@ -25,8 +25,6 @@ Gram invariant), which is exactly the freedom the paper exploits.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -99,9 +97,6 @@ def distributed_postprocess_r0(
     r0: jnp.ndarray,
     mesh: Mesh,
     axis: str = "data",
-    *,
-    panel: int = 32,
-    use_kernel: bool = False,
 ) -> jnp.ndarray:
     """R₀ (M×N) → R (N×N) with rows sharded over ``mesh[axis]`` (THIN on TPU)."""
     m, n = r0.shape
@@ -114,11 +109,8 @@ def distributed_postprocess_r0(
     # mesh-wide computation.
     r0 = jax.device_put(r0, NamedSharding(mesh, P(axis, None)))
 
-    local_qr = functools.partial(blocked_qr_r, panel=panel,
-                                 use_kernel=use_kernel)
-
     def shard_fn(block):  # [mp/p, n] per shard
-        r_local = local_qr(block)
+        r_local = blocked_qr_r(block)
         return butterfly_qr_combine(r_local, axis, p, leaf_qr=householder_qr_r)
 
     fn = shard_map(
@@ -130,10 +122,10 @@ def distributed_postprocess_r0(
     return normalize_sign(out[:n])
 
 
-def distributed_qr_r(a: jnp.ndarray, mesh: Mesh, axis: str = "data",
-                     **kw) -> jnp.ndarray:
+def distributed_qr_r(a: jnp.ndarray, mesh: Mesh,
+                     axis: str = "data") -> jnp.ndarray:
     """General tall-skinny distributed QR (used by optim.orthogonal too)."""
-    return distributed_postprocess_r0(a, mesh, axis, **kw)
+    return distributed_postprocess_r0(a, mesh, axis)
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +181,6 @@ def partitioned_figaro_qr(
     num_parts: int,
     *,
     dtype=jnp.float64,
-    method: str = "tsqr",
     use_kernel: bool = False,
     assembly: str = "padded",
     engine=None,
@@ -220,8 +211,8 @@ def partitioned_figaro_qr(
         engine = default_session().engine
     parts = partition_fact_table(tree, num_parts)
     if mesh is None:
-        rs = [engine.qr(build_plan(t), dtype=dtype, method=method,
-                        use_kernel=use_kernel, assembly=assembly)
+        rs = [engine.qr(build_plan(t), dtype=dtype, use_kernel=use_kernel,
+                        assembly=assembly)
               for t in parts]
         stacked = jnp.concatenate(rs, axis=0)
         return normalize_sign(tsqr_r(stacked, leaf_rows=max(
@@ -230,11 +221,10 @@ def partitioned_figaro_qr(
     rs = []
     for i, t in enumerate(parts):
         with jax.default_device(slots[i % slots.size]):
-            rs.append(engine.qr(build_plan(t), dtype=dtype, method=method,
+            rs.append(engine.qr(build_plan(t), dtype=dtype,
                                 use_kernel=use_kernel, assembly=assembly))
     # Colocate the per-slot Rs before stacking (cross-device concat is an
     # error), then THIN-combine the [P·N, N] stack over the mesh.
     stacked = jnp.concatenate(
         [jax.device_put(r, slots[0]) for r in rs], axis=0)
-    return distributed_postprocess_r0(stacked, mesh, axis,
-                                      use_kernel=use_kernel)
+    return distributed_postprocess_r0(stacked, mesh, axis)

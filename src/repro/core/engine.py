@@ -16,7 +16,9 @@ pytree argument:
     per-node data matrices with the plan held fixed — one join structure
     serving many feature-sets/users per dispatch. This is the "one
     factorization, many downstream reads" leverage: everything downstream
-    (SVD, PCA, regression) reads off the one R.
+    (SVD, PCA, regression) reads off the one R. Each kind has one traced
+    body, ``_<kind>_impl``; its batched kind is that body under `jax.vmap`,
+    and the body's keyword-only options are the static arguments.
 
   * ``shard=mesh`` (or ``shard=(mesh, axis)``) additionally splits the leading
     request-batch axis of a batched dispatch over the mesh axis with
@@ -55,6 +57,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import inspect
 
 import jax
 import jax.numpy as jnp
@@ -165,28 +168,6 @@ class FigaroEngine:
     (``None``) keeps every executable, the pre-existing behavior.
     """
 
-    _STATIC = {
-        "r0": ("dtype", "use_kernel", "assembly"),
-        "r0_batched": ("dtype", "use_kernel", "assembly"),
-        "qr": ("dtype", "method", "leaf_rows", "panel", "use_kernel",
-               "assembly"),
-        "qr_batched": ("dtype", "method", "leaf_rows", "panel", "use_kernel",
-                       "assembly"),
-        "svd": ("dtype", "method", "leaf_rows", "panel", "use_kernel",
-                "assembly"),
-        "svd_batched": ("dtype", "method", "leaf_rows", "panel", "use_kernel",
-                        "assembly"),
-        "pca": ("dtype", "k", "center", "method", "leaf_rows", "panel",
-                "use_kernel", "assembly"),
-        "pca_batched": ("dtype", "k", "center", "method", "leaf_rows",
-                        "panel", "use_kernel", "assembly"),
-        "least_squares": ("dtype", "label_col", "ridge", "method",
-                          "leaf_rows", "panel", "use_kernel", "assembly"),
-        "least_squares_batched": ("dtype", "label_col", "ridge", "method",
-                                  "leaf_rows", "panel", "use_kernel",
-                                  "assembly"),
-    }
-
     def __init__(self, *, donate_data: bool = True,
                  max_cached: int | None = None):
         if max_cached is not None and max_cached < 1:
@@ -290,7 +271,25 @@ class FigaroEngine:
         return mesh, axis
 
     def _make_jitted(self, kind: str, donate: bool, mesh, axis, key: tuple):
-        impl = getattr(self, f"_{kind}_impl")
+        base = kind.removesuffix("_batched")
+        impl = getattr(self, f"_{base}_impl")
+        # Every keyword-only option of the body is static: part of the
+        # executable cache key, concrete at trace time.
+        static = tuple(name for name, p in
+                       inspect.signature(impl).parameters.items()
+                       if p.kind is inspect.Parameter.KEYWORD_ONLY)
+        if kind != base:
+            one = impl
+
+            # A batched kind vmaps the body over the request axis with the
+            # plan held unmapped. Its name stays `_<kind>_impl`, so the
+            # compiled module is `jit__<kind>_impl`, the name device traces
+            # are classified by.
+            @functools.wraps(one)
+            def impl(plan, data, **options):
+                return jax.vmap(lambda d: one(plan, d, **options))(data)
+
+            impl.__name__ = impl.__qualname__ = f"_{kind}_impl"
         if mesh is None:
             inner = impl
         else:
@@ -322,7 +321,7 @@ class FigaroEngine:
                     _san_retrace.note_trace(kind, key)
             return inner(plan, data, **options)
 
-        return jax.jit(wrapper, static_argnames=self._STATIC[kind],
+        return jax.jit(wrapper, static_argnames=static,
                        donate_argnums=(1,) if donate else ())
 
     def _dispatch(self, kind: str, plan: FigaroPlan, data, *, shard=None,
@@ -446,56 +445,23 @@ class FigaroEngine:
         return figaro_r0(plan, list(data), dtype=dtype, use_kernel=use_kernel,
                          assembly=assembly)
 
-    def _r0_batched_impl(self, plan, data, *, dtype, use_kernel, assembly):
-        return jax.vmap(lambda d: figaro_r0(
-            plan, list(d), dtype=dtype, use_kernel=use_kernel,
-            assembly=assembly))(data)
+    def _qr_impl(self, plan, data, *, dtype, leaf_rows, use_kernel, assembly):
+        r0 = self._r0_impl(plan, data, dtype=dtype, use_kernel=use_kernel,
+                           assembly=assembly)
+        return postprocess_r0(r0, leaf_rows=leaf_rows)
 
-    def _qr_one(self, plan, data, *, dtype, method, leaf_rows, panel,
-                use_kernel, assembly):
-        r0 = figaro_r0(plan, list(data), dtype=dtype, use_kernel=use_kernel,
-                       assembly=assembly)
-        return postprocess_r0(r0, method=method, leaf_rows=leaf_rows,
-                              panel=panel, use_kernel=use_kernel)
-
-    def _qr_impl(self, plan, data, *, dtype, method, leaf_rows, panel,
-                 use_kernel, assembly):
-        return self._qr_one(plan, data, dtype=dtype, method=method,
-                            leaf_rows=leaf_rows, panel=panel,
-                            use_kernel=use_kernel, assembly=assembly)
-
-    def _qr_batched_impl(self, plan, data, *, dtype, method, leaf_rows, panel,
-                         use_kernel, assembly):
-        return jax.vmap(lambda d: self._qr_one(
-            plan, d, dtype=dtype, method=method, leaf_rows=leaf_rows,
-            panel=panel, use_kernel=use_kernel, assembly=assembly))(data)
-
-    def _svd_one(self, plan, data, *, dtype, method, leaf_rows, panel,
-                 use_kernel, assembly):
-        r = self._qr_one(plan, data, dtype=dtype, method=method,
-                         leaf_rows=leaf_rows, panel=panel,
-                         use_kernel=use_kernel, assembly=assembly)
+    def _svd_impl(self, plan, data, *, dtype, leaf_rows, use_kernel,
+                  assembly):
+        r = self._qr_impl(plan, data, dtype=dtype, leaf_rows=leaf_rows,
+                          use_kernel=use_kernel, assembly=assembly)
         with jax.named_scope("figaro.downstream"):
             _, s, vt = jnp.linalg.svd(r)
         return s, vt
 
-    def _svd_impl(self, plan, data, *, dtype, method, leaf_rows, panel,
+    def _pca_impl(self, plan, data, *, k, center, dtype, leaf_rows,
                   use_kernel, assembly):
-        return self._svd_one(plan, data, dtype=dtype, method=method,
-                             leaf_rows=leaf_rows, panel=panel,
-                             use_kernel=use_kernel, assembly=assembly)
-
-    def _svd_batched_impl(self, plan, data, *, dtype, method, leaf_rows,
-                          panel, use_kernel, assembly):
-        return jax.vmap(lambda d: self._svd_one(
-            plan, d, dtype=dtype, method=method, leaf_rows=leaf_rows,
-            panel=panel, use_kernel=use_kernel, assembly=assembly))(data)
-
-    def _pca_one(self, plan, data, *, k, center, dtype, method, leaf_rows,
-                 panel, use_kernel, assembly):
-        r = self._qr_one(plan, data, dtype=dtype, method=method,
-                         leaf_rows=leaf_rows, panel=panel,
-                         use_kernel=use_kernel, assembly=assembly)
+        r = self._qr_impl(plan, data, dtype=dtype, leaf_rows=leaf_rows,
+                          use_kernel=use_kernel, assembly=assembly)
         sums, total = _column_moments(plan, data, dtype)
         with jax.named_scope("figaro.downstream"):
             mean = sums / total
@@ -513,24 +479,10 @@ class FigaroEngine:
                          explained_variance=evals[order],
                          mean=mean, num_rows=total)
 
-    def _pca_impl(self, plan, data, *, k, center, dtype, method, leaf_rows,
-                  panel, use_kernel, assembly):
-        return self._pca_one(plan, data, k=k, center=center, dtype=dtype,
-                             method=method, leaf_rows=leaf_rows, panel=panel,
-                             use_kernel=use_kernel, assembly=assembly)
-
-    def _pca_batched_impl(self, plan, data, *, k, center, dtype, method,
-                          leaf_rows, panel, use_kernel, assembly):
-        return jax.vmap(lambda d: self._pca_one(
-            plan, d, k=k, center=center, dtype=dtype, method=method,
-            leaf_rows=leaf_rows, panel=panel, use_kernel=use_kernel,
-            assembly=assembly))(data)
-
-    def _least_squares_one(self, plan, data, *, label_col, ridge, dtype,
-                           method, leaf_rows, panel, use_kernel, assembly):
-        r = self._qr_one(plan, data, dtype=dtype, method=method,
-                         leaf_rows=leaf_rows, panel=panel,
-                         use_kernel=use_kernel, assembly=assembly)
+    def _least_squares_impl(self, plan, data, *, label_col, ridge, dtype,
+                            leaf_rows, use_kernel, assembly):
+        r = self._qr_impl(plan, data, dtype=dtype, leaf_rows=leaf_rows,
+                          use_kernel=use_kernel, assembly=assembly)
         with jax.named_scope("figaro.downstream"):
             n = plan.spec.num_cols
             feat = jnp.array([j for j in range(n) if j != label_col])
@@ -552,21 +504,6 @@ class FigaroEngine:
                                                          lower=False)
                 resid = jnp.abs(rr[n - 1, n - 1])
         return beta, resid
-
-    def _least_squares_impl(self, plan, data, *, label_col, ridge, dtype,
-                            method, leaf_rows, panel, use_kernel, assembly):
-        return self._least_squares_one(
-            plan, data, label_col=label_col, ridge=ridge, dtype=dtype,
-            method=method, leaf_rows=leaf_rows, panel=panel,
-            use_kernel=use_kernel, assembly=assembly)
-
-    def _least_squares_batched_impl(self, plan, data, *, label_col, ridge,
-                                    dtype, method, leaf_rows, panel,
-                                    use_kernel, assembly):
-        return jax.vmap(lambda d: self._least_squares_one(
-            plan, d, label_col=label_col, ridge=ridge, dtype=dtype,
-            method=method, leaf_rows=leaf_rows, panel=panel,
-            use_kernel=use_kernel, assembly=assembly))(data)
 
     # -- public API ----------------------------------------------------------
 
@@ -605,35 +542,31 @@ class FigaroEngine:
 
     def qr(self, plan: FigaroPlan, data=None, *, batched: bool = False,
            shard=None, bucket: bool = False, batch_capacity: int | None = None,
-           dtype=jnp.float32, method: str = "tsqr", leaf_rows: int = 256,
-           panel: int = 32, use_kernel: bool = False,
+           dtype=jnp.float32, leaf_rows: int = 256, use_kernel: bool = False,
            assembly: str = "padded") -> jnp.ndarray:
         """Upper-triangular R of the join's QR ([B, N, N] when batched)."""
         return self._dispatch(
             "qr_batched" if batched else "qr", plan, data, shard=shard,
             bucket=bucket, batch_capacity=batch_capacity,
-            dtype=self._canon(dtype), method=method,
-            leaf_rows=leaf_rows, panel=panel, use_kernel=use_kernel,
-            assembly=assembly)
+            dtype=self._canon(dtype), leaf_rows=leaf_rows,
+            use_kernel=use_kernel, assembly=assembly)
 
     def svd(self, plan: FigaroPlan, data=None, *, batched: bool = False,
             shard=None, bucket: bool = False,
             batch_capacity: int | None = None, dtype=jnp.float64,
-            method: str = "tsqr", leaf_rows: int = 256, panel: int = 32,
-            use_kernel: bool = False, assembly: str = "padded"):
+            leaf_rows: int = 256, use_kernel: bool = False,
+            assembly: str = "padded"):
         """Singular values + right-singular vectors of the join matrix."""
         return self._dispatch(
             "svd_batched" if batched else "svd", plan, data, shard=shard,
             bucket=bucket, batch_capacity=batch_capacity,
-            dtype=self._canon(dtype), method=method,
-            leaf_rows=leaf_rows, panel=panel, use_kernel=use_kernel,
-            assembly=assembly)
+            dtype=self._canon(dtype), leaf_rows=leaf_rows,
+            use_kernel=use_kernel, assembly=assembly)
 
     def pca(self, plan: FigaroPlan, data=None, *, batched: bool = False,
             shard=None, bucket: bool = False,
             batch_capacity: int | None = None, k: int | None = None,
-            center: bool = True, dtype=jnp.float64, method: str = "tsqr",
-            leaf_rows: int = 256, panel: int = 32,
+            center: bool = True, dtype=jnp.float64, leaf_rows: int = 256,
             use_kernel: bool = False,
             assembly: str = "padded") -> PCAResult:
         """PCA of the join matrix from R (+ factorized means when centering)."""
@@ -642,25 +575,22 @@ class FigaroEngine:
         return self._dispatch(
             "pca_batched" if batched else "pca", plan, data, shard=shard,
             bucket=bucket, batch_capacity=batch_capacity, k=k, center=center,
-            dtype=self._canon(dtype),
-            method=method, leaf_rows=leaf_rows, panel=panel,
+            dtype=self._canon(dtype), leaf_rows=leaf_rows,
             use_kernel=use_kernel, assembly=assembly)
 
     def least_squares(self, plan: FigaroPlan, label_col: int, data=None, *,
                       batched: bool = False, shard=None, bucket: bool = False,
                       batch_capacity: int | None = None,
                       ridge: float = 0.0, dtype=jnp.float64,
-                      method: str = "tsqr", leaf_rows: int = 256,
-                      panel: int = 32, use_kernel: bool = False,
+                      leaf_rows: int = 256, use_kernel: bool = False,
                       assembly: str = "padded"):
         """argmin_β ‖A[:, feats]·β − A[:, label]‖² over the unmaterialized join."""
         return self._dispatch(
             "least_squares_batched" if batched else "least_squares", plan,
             data, shard=shard, bucket=bucket, batch_capacity=batch_capacity,
             label_col=label_col,
-            ridge=float(ridge), dtype=self._canon(dtype), method=method,
-            leaf_rows=leaf_rows, panel=panel, use_kernel=use_kernel,
-            assembly=assembly)
+            ridge=float(ridge), dtype=self._canon(dtype),
+            leaf_rows=leaf_rows, use_kernel=use_kernel, assembly=assembly)
 
 
 def _plan_arg_error(arg_name: str, value) -> str:

@@ -33,7 +33,7 @@ Two hot-path variants, both cache-keyed by the engine:
     (default) is the XLA path — `segmented_head_tail` per pass — which stays
     the CPU fallback. Both paths take the heads from the inclusive sums at
     each segment's last row, a gather of K indices, instead of a second
-    [m, n] reduction.
+    [m, n] reduction. Post-processing does not read ``use_kernel``.
 
   * ``assembly`` picks how the emitted slabs become R₀. ``"padded"``
     (default) pads every slab to the full ``num_cols`` width and concatenates

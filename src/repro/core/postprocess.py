@@ -6,21 +6,19 @@ binary combine tree). Here:
 
   * `householder_qr_r`   — column-at-a-time Householder, pure JAX `fori_loop`
                            (the in-house leaf factorization; MKL-analog).
-  * `blocked_qr_r`       — panel/WY blocked variant; the panel factorization
-                           can be served by the Pallas `panel_qr` kernel.
+  * `blocked_qr_r`       — panel/WY blocked variant (the mesh leaves in
+                           `core/distributed.py`).
   * `tsqr_r`             — row-blocked leaf QRs + log₂ pairwise combine
                            (THIN on TPU; the mesh version lives in
                            `core/distributed.py`).
-  * `postprocess_r0`     — R₀ → upper-triangular R with non-negative diagonal;
-                           float32 TSQR on a TPU runs every level on the
+  * `postprocess_r0`     — R₀ → upper-triangular R with non-negative diagonal
+                           by TSQR; float32 on a TPU runs every level on the
                            Pallas `tsqr_leaf` kernel (`kernels/tsqr_leaf`).
 
 All functions return only R (the paper never materializes Q either).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -106,11 +104,7 @@ def _panel_to_wy(v: jnp.ndarray, beta: jnp.ndarray) -> jnp.ndarray:
 
 
 def householder_panel(a: jnp.ndarray):
-    """Factor a panel: returns (V unit-lower reflectors [m, nb], beta [nb], R_panel [m, nb]).
-
-    Pure-JAX reference; `repro.kernels.panel_qr` implements the same contract
-    as a Pallas kernel (validated against this in tests).
-    """
+    """Factor a panel: returns (V unit-lower reflectors [m, nb], beta [nb], R_panel [m, nb])."""
     m, nb = a.shape
     dtype = a.dtype
     rows = jnp.arange(m)
@@ -139,8 +133,7 @@ def householder_panel(a: jnp.ndarray):
     return vs, betas, a
 
 
-def blocked_qr_r(a: jnp.ndarray, panel: int = 32, *,
-                 use_kernel: bool = False) -> jnp.ndarray:
+def blocked_qr_r(a: jnp.ndarray, panel: int = 32) -> jnp.ndarray:
     """Blocked Householder QR (panel + compact-WY trailing update) -> R [n, n]."""
     m, n = a.shape
     if m < n:
@@ -150,11 +143,7 @@ def blocked_qr_r(a: jnp.ndarray, panel: int = 32, *,
     while pos < n:
         nb = min(panel, n - pos)
         block = a[pos:, pos:pos + nb]
-        if use_kernel:
-            from repro.kernels.panel_qr import ops as pq_ops
-            v, beta, rp = pq_ops.panel_qr(block)
-        else:
-            v, beta, rp = householder_panel(block)
+        v, beta, rp = householder_panel(block)
         t = _panel_to_wy(v, beta)
         a = a.at[pos:, pos:pos + nb].set(rp)
         if pos + nb < n:
@@ -201,33 +190,17 @@ def _tsqr_on_kernel(r0: jnp.ndarray, leaf_rows: int) -> bool:
             and tl_kernel.fits(n, max(leaf_rows, 2 * n)))
 
 
-def postprocess_r0(r0: jnp.ndarray, *, method: str = "tsqr",
-                   leaf_rows: int = 256, panel: int = 32,
-                   use_kernel: bool = False) -> jnp.ndarray:
-    """R₀ (M×N, almost upper-triangular) → R (N×N, diag ≥ 0), under the
-    ``figaro.postprocess`` named scope.
+def postprocess_r0(r0: jnp.ndarray, *, leaf_rows: int = 256) -> jnp.ndarray:
+    """R₀ (M×N, almost upper-triangular) → R (N×N, diag ≥ 0) by TSQR over
+    ``leaf_rows``-row leaves, under the ``figaro.postprocess`` named scope.
 
-    ``method="tsqr"`` in float32 on a TPU runs on the `tsqr_leaf` kernel
-    whatever ``use_kernel`` says; elsewhere its leaves are `householder_qr_r`,
-    or blocked WY with the `panel_qr` kernel under ``use_kernel``.
+    In float32 on a TPU every level runs on the `tsqr_leaf` kernel; elsewhere
+    the leaves are `householder_qr_r`.
     """
     with jax.named_scope("figaro.postprocess"):
-        if method == "tsqr" and _tsqr_on_kernel(r0, leaf_rows):
+        if _tsqr_on_kernel(r0, leaf_rows):
             from repro.kernels.tsqr_leaf import ops as tl_ops
             r = tl_ops.tsqr_r(r0, leaf_rows=leaf_rows)
-        elif method == "tsqr":
-            leaf = functools.partial(blocked_qr_r, panel=panel,
-                                     use_kernel=use_kernel) \
-                if use_kernel else householder_qr_r
-            r = tsqr_r(r0, leaf_rows=leaf_rows, leaf_qr=leaf)
-        elif method == "householder":
-            r = householder_qr_r(r0)
-        elif method == "blocked":
-            r = blocked_qr_r(r0, panel=panel, use_kernel=use_kernel)
-        elif method == "lapack":  # XLA's native QR (the openblas/MKL analog)
-            r = jnp.linalg.qr(r0, mode="r")
-            n = r0.shape[1]
-            r = r[:n]
         else:
-            raise ValueError(f"unknown postprocess method {method!r}")
+            r = tsqr_r(r0, leaf_rows=leaf_rows)
         return normalize_sign(r)

@@ -42,14 +42,12 @@ def figaro_qr(
     data=None,
     *,
     dtype=jnp.float32,
-    method: str = "tsqr",
     leaf_rows: int = 256,
     use_kernel: bool = False,
 ) -> jnp.ndarray:
     """Upper-triangular R of the QR decomposition of the (unmaterialized) join."""
     return _session().qr(tree_or_plan, data, batched=False, dtype=dtype,
-                         method=method, leaf_rows=leaf_rows,
-                         use_kernel=use_kernel)
+                         leaf_rows=leaf_rows, use_kernel=use_kernel)
 
 
 def figaro_qr_batched(
@@ -57,20 +55,17 @@ def figaro_qr_batched(
     data_batch,
     *,
     dtype=jnp.float32,
-    method: str = "tsqr",
     leaf_rows: int = 256,
     use_kernel: bool = False,
 ) -> jnp.ndarray:
     """R for a batch of feature-sets over one join structure: ``data_batch[i]``
     is [B, m_i, n_i]; returns [B, N, N] from a single compiled dispatch."""
     return _session().qr(tree_or_plan, data_batch, batched=True, dtype=dtype,
-                         method=method, leaf_rows=leaf_rows,
-                         use_kernel=use_kernel)
+                         leaf_rows=leaf_rows, use_kernel=use_kernel)
 
 
 def figaro_qr_fn(plan: FigaroPlan, *, dtype=jnp.float32,
-                 method: str = "tsqr", leaf_rows: int = 256,
-                 use_kernel: bool = False):
+                 leaf_rows: int = 256, use_kernel: bool = False):
     """A jitted closure ``data_list -> R`` for a fixed plan.
 
     One compiled program for counts + Algorithm 2 + post-processing, with the
@@ -84,8 +79,7 @@ def figaro_qr_fn(plan: FigaroPlan, *, dtype=jnp.float32,
 
     def fn(data):
         r0 = figaro_r0(plan, data, dtype=dtype, use_kernel=use_kernel)
-        return postprocess_r0(r0, method=method, leaf_rows=leaf_rows,
-                              use_kernel=use_kernel)
+        return postprocess_r0(r0, leaf_rows=leaf_rows)
 
     # Deliberately plan-closed: this factory exists for dispatch-minimal
     # wall-clock benchmarks; plan-generic dispatch lives in FigaroEngine.

@@ -4,7 +4,6 @@
   head_tail/   segmented generalized head/tail — the unfused building block
   tsqr_leaf/   Householder R of a stack of TSQR leaves in VMEM — float32
                post-processing on a TPU, every TSQR level
-  panel_qr/    Householder panel factorization — blocked QR under use_kernel
   flash_attn/  fused GQA attention — serving-side mixer hot spot
 
 Platform policy (compiled on TPU/GPU, interpreted elsewhere, explicit

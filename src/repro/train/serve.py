@@ -60,8 +60,8 @@ class FigaroServer(AsyncFigaroServer):
 def make_figaro_server(plan: FigaroPlan | PlanHolder, *, kind: str = "qr",
                        label_col: int | None = None, k: int | None = None,
                        ridge: float = 0.0,
-                       dtype=jnp.float32, method: str = "tsqr",
-                       leaf_rows: int = 256, use_kernel: bool = False,
+                       dtype=jnp.float32, leaf_rows: int = 256,
+                       use_kernel: bool = False,
                        assembly: str = "padded",
                        engine: FigaroEngine | None = None,
                        mesh: Mesh | None = None, shard_axis: str = "data",
@@ -124,7 +124,7 @@ def make_figaro_server(plan: FigaroPlan | PlanHolder, *, kind: str = "qr",
     # serving executables are the fused-kernel / band-assembly programs when
     # the session (or caller) asked for them — same cache-key discipline as
     # direct engine calls.
-    common = dict(batched=True, shard=shard, dtype=dtype, method=method,
+    common = dict(batched=True, shard=shard, dtype=dtype,
                   leaf_rows=leaf_rows, use_kernel=use_kernel,
                   assembly=assembly)
     dispatch = {

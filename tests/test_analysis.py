@@ -65,37 +65,6 @@ def test_fig001_exempts_the_shim_itself():
 
 # -- FIG002 retrace hazards --------------------------------------------------
 
-FIG002_STATIC_DRIFT = """
-    import functools
-    import jax
-
-    class Engine:
-        _STATIC = {
-            "qr": ("dtype", "use_kernel", "method"),
-            "svd": ("dtype",),
-        }
-
-        def _qr_impl(self, plan, data, *, dtype, use_kernel):
-            return data
-
-        def _svd_impl(self, plan, data, *, dtype, rank):
-            return data
-"""
-
-FIG002_STATIC_GOOD = """
-    class Engine:
-        _STATIC = {
-            "qr": ("dtype", "use_kernel"),
-            "svd": ("dtype", "rank"),
-        }
-
-        def _qr_impl(self, plan, data, *, dtype, use_kernel):
-            return data
-
-        def _svd_impl(self, plan, data, *, dtype, rank):
-            return data
-"""
-
 FIG002_PLAN_CLOSURE = """
     import jax
 
@@ -131,18 +100,6 @@ FIG002_UNHASHABLE = """
     def solve(data, *, opts=[]):
         return data
 """
-
-
-def test_fig002_static_table_drift():
-    msgs = [f.message for f in _findings(FIG002_STATIC_DRIFT)
-            if f.rule == "FIG002"]
-    joined = "\n".join(msgs)
-    assert "'method'" in joined and "does not accept" in joined
-    assert "'rank'" in joined and "missing impl keyword" in joined
-
-
-def test_fig002_static_table_in_sync_is_quiet():
-    assert "FIG002" not in _rules_fired(FIG002_STATIC_GOOD)
 
 
 def test_fig002_plan_closure():
@@ -832,8 +789,6 @@ def test_fig009_metadata_and_host_paths_quiet():
 def test_fig009_static_kwonly_param_is_concrete():
     src = """
         class Eng:
-            _STATIC = {"qr": ("panel",)}
-
             def _qr_impl(self, plan, data, *, panel):
                 cols = int(panel)
                 return data * cols

@@ -2,6 +2,7 @@
 and the scatter-free R₀ assembly path."""
 
 import contextlib
+import inspect
 import re
 
 import jax
@@ -237,6 +238,83 @@ def test_engine_svd_cartesian_edge():
     assert engine.trace_count("svd") == 1
 
 
+# -- one body per kind: batching and static options --------------------------
+
+KINDS = ["r0", "qr", "svd", "pca", "least_squares"]
+
+
+def _dispatch(engine, kind, plan, data, **options):
+    """One dispatch through the kind's public engine method; least_squares
+    takes its label column positionally."""
+    if kind == "least_squares":
+        label = options.pop("label_col", plan.num_cols - 1)
+        return engine.least_squares(plan, label, data, **options)
+    return getattr(engine, kind)(plan, data, **options)
+
+
+def _comparable(kind, out):
+    """The sign-free arrays of one answer: SVD and PCA as their Gram
+    reconstructions, whose rows' signs do not matter."""
+    if kind == "svd":
+        s, vt = (np.asarray(x) for x in out)
+        return [s, (vt.T * s ** 2) @ vt]
+    if kind == "pca":
+        comp = np.asarray(out.components)
+        ev = np.asarray(out.explained_variance)
+        return [(comp.T * ev) @ comp, ev, np.asarray(out.mean),
+                np.asarray(out.num_rows)]
+    return [np.asarray(x) for x in jax.tree.leaves(out)]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_every_kind_batched_matches_per_request(rng, kind):
+    """The batched kind is the per-request body under vmap: one trace
+    answers the batch, and each answer equals the request's own dispatch."""
+    _, plan = _plan("star", rng)
+    engine = FigaroEngine(donate_data=False)
+    batch = _batch(plan, rng, 3, np.float64)
+    out = _dispatch(engine, kind, plan, batch, batched=True,
+                    dtype=jnp.float64)
+    assert engine.trace_count(f"{kind}_batched") == 1
+    for i in range(3):
+        ref = _dispatch(engine, kind, plan, [d[i] for d in batch],
+                        dtype=jnp.float64)
+        got = jax.tree.map(lambda x: x[i], out)
+        for g, r in zip(_comparable(kind, got), _comparable(kind, ref)):
+            np.testing.assert_allclose(g, r, atol=1e-10 * max(
+                np.abs(r).max(), 1.0), err_msg=f"{kind}[{i}]")
+    assert engine.trace_count(kind) == 1
+
+
+# A value other than the test's own for every keyword-only option of a body.
+OPTION_CHANGES = {"dtype": jnp.float32, "leaf_rows": 8, "use_kernel": True,
+                  "assembly": "band", "k": 1, "center": False,
+                  "label_col": 0, "ridge": 0.5}
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_static_options_reach_the_cache_key(rng, kind):
+    """Every keyword-only option of the kind's body is part of the cache
+    key: a repeat hits, and each changed option traces exactly once more."""
+    _, plan = _plan("path", rng)
+    engine = FigaroEngine(donate_data=False)
+    params = inspect.signature(getattr(engine, f"_{kind}_impl")).parameters
+    static = [name for name, p in params.items()
+              if p.kind is inspect.Parameter.KEYWORD_ONLY]
+    assert set(static) <= set(OPTION_CHANGES), static
+    _dispatch(engine, kind, plan, None, dtype=jnp.float64)
+    _dispatch(engine, kind, plan, None, dtype=jnp.float64)
+    assert engine.trace_count(kind) == 1
+    for n, name in enumerate(static, start=2):
+        options = dict(dtype=jnp.float64)
+        options[name] = OPTION_CHANGES[name]
+        _dispatch(engine, kind, plan, None, **options)
+        _dispatch(engine, kind, plan, None, **options)
+        assert engine.trace_count(kind) == n, name
+    _dispatch(engine, kind, plan, None, dtype=jnp.float64)
+    assert engine.trace_count(kind) == len(static) + 1
+
+
 # -- genuinely-batched pca / least_squares ------------------------------------
 
 
@@ -401,13 +479,12 @@ KIND_OPTIONS = {"qr": {}, "pca": {"k": 2, "center": True}}
 
 def _compiled_hlo(kind, plan, data, leaf_rows=256):
     """Optimized HLO of the batched ``kind`` program as the engine jits it."""
-    engine = FigaroEngine(donate_data=False)
-    impl = getattr(engine, f"_{kind}_batched_impl")
+    fn = FigaroEngine(donate_data=False)._make_jitted(
+        f"{kind}_batched", False, None, None, None)
     options = dict(KIND_OPTIONS[kind], dtype=np.dtype(np.float64),
-                   method="tsqr", leaf_rows=leaf_rows, panel=32,
-                   use_kernel=False, assembly="padded")
-    fn = jax.jit(lambda p, d: impl(p, d, **options))
-    return fn.lower(plan.without_data(), data).compile().as_text()
+                   leaf_rows=leaf_rows, use_kernel=False, assembly="padded")
+    return fn.lower(plan.without_data(), data,
+                    **options).compile().as_text()
 
 
 @pytest.mark.parametrize("kind", list(KIND_OPTIONS))

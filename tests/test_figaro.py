@@ -8,7 +8,8 @@ from hypothesis import given, settings, strategies as st
 from repro.core.figaro import figaro_r0
 from repro.core.join_tree import JoinTree, build_plan
 from repro.core.materialize import materialize_join
-from repro.core.postprocess import normalize_sign
+from repro.core.postprocess import (blocked_qr_r, householder_qr_r,
+                                    normalize_sign, tsqr_r)
 from repro.core.qr import figaro_qr, materialized_qr
 from repro.data.relational import (cartesian, favorita_like, retailer_like,
                                    yelp_like)
@@ -51,15 +52,23 @@ def test_r0_independent_of_join_size(rng):
 # -- end-to-end: R matches QR of the materialized join ------------------------
 
 
-@pytest.mark.parametrize("method", ["householder", "tsqr", "blocked",
-                                    "lapack"])
-def test_figaro_qr_matches_materialized(rng, method):
+FACTORIZATIONS = {
+    "householder": householder_qr_r,
+    "tsqr": lambda a: tsqr_r(a, leaf_rows=16),
+    "blocked": blocked_qr_r,
+    "lapack": lambda a: jnp.linalg.qr(a, mode="r")[:a.shape[1]],
+}
+
+
+@pytest.mark.parametrize("factorization", list(FACTORIZATIONS))
+def test_figaro_qr_matches_materialized(rng, factorization):
+    """Any QR of FiGaRo's float64 R₀ is the QR of the materialized join."""
     _, tree, plan = random_acyclic_db("snowflake4", rng)
-    r_fig = np.asarray(figaro_qr(plan, dtype=jnp.float64, method=method,
-                                 leaf_rows=16))
+    r0 = figaro_r0(plan, dtype=jnp.float64)
+    r_fig = np.asarray(normalize_sign(FACTORIZATIONS[factorization](r0)))
     r_mat = np.asarray(materialized_qr(tree, method="lapack"))
     err = np.abs(r_fig - r_mat).max() / np.abs(r_mat).max()
-    assert err < 1e-9, (method, err)
+    assert err < 1e-9, (factorization, err)
 
 
 @pytest.mark.parametrize("maker,kw", [

@@ -8,9 +8,7 @@ import numpy as np
 import pytest
 
 from repro.core.heads_tails import segmented_head_tail
-from repro.core.postprocess import blocked_qr_r, normalize_sign
 from repro.kernels.head_tail import ops as ht_ops, ref as ht_ref
-from repro.kernels.panel_qr import ops as pq_ops, ref as pq_ref
 
 
 # -- head_tail ----------------------------------------------------------------
@@ -105,33 +103,3 @@ def test_head_tail_kernel_single_row_segments(rng):
                                       use_kernel=True)
     np.testing.assert_allclose(np.asarray(t), 0, atol=0)
     np.testing.assert_allclose(np.asarray(h), np.asarray(data), rtol=1e-6)
-
-
-# -- panel_qr -----------------------------------------------------------------
-
-
-@pytest.mark.parametrize("m,nb", [(8, 4), (64, 16), (200, 32), (256, 128)])
-def test_panel_qr_kernel_sweep(rng, m, nb):
-    a = jnp.array(rng.normal(size=(m, nb)), jnp.float32)
-    v1, b1, r1 = pq_ops.panel_qr(a)
-    v2, b2, r2 = pq_ref.panel_qr_ref(a)
-    assert np.abs(np.asarray(v1) - np.asarray(v2)).max() < 2e-3
-    assert np.abs(np.asarray(b1) - np.asarray(b2)).max() < 2e-3
-    assert np.abs(np.asarray(r1) - np.asarray(r2)).max() < 2e-3
-
-
-def test_panel_qr_r_is_valid_qr(rng):
-    """R from the kernel agrees with lapack on the same panel (up to sign)."""
-    a32 = rng.normal(size=(96, 16)).astype(np.float32)
-    _, _, r = pq_ops.panel_qr(jnp.array(a32))
-    r_np = np.triu(np.asarray(r)[:16])
-    ref = np.linalg.qr(a32)[1]
-    flip = np.sign(np.diag(r_np)) * np.sign(np.diag(ref))
-    np.testing.assert_allclose(r_np * flip[:, None], ref, atol=5e-4)
-
-
-def test_blocked_qr_with_kernel_path(rng):
-    x = jnp.array(rng.normal(size=(300, 64)), jnp.float32)
-    rk = normalize_sign(blocked_qr_r(x, panel=32, use_kernel=True))
-    rr = normalize_sign(jnp.linalg.qr(x, mode="r"))
-    assert np.abs(np.asarray(rk) - np.asarray(rr)).max() < 5e-3

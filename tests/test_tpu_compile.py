@@ -26,7 +26,6 @@ from repro.core.plan_cache import build_capacity_plan
 from repro.data.relational import yelp_like
 from repro.kernels import _platform
 from repro.kernels.node_fused.kernel import node_fused_kernel
-from repro.kernels.panel_qr.kernel import panel_qr_kernel
 from repro.kernels.tsqr_leaf import ops as tsqr_leaf_ops
 
 from helpers import hlo_instructions
@@ -69,13 +68,6 @@ def test_node_fused_compiles_at_full_size(one_chip, m, n):
     compiled = node_fused_kernel.lower(
         _spec((m, n), jnp.float32, one_chip), col, col, col, col, col, col,
         interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_panel_qr_compiles_at_tsqr_leaf(one_chip):
-    # The kernel path's TSQR leaf: leaf_rows=256 rows, one panel=32 wide.
-    compiled = panel_qr_kernel.lower(
-        _spec((256, 32), jnp.float32, one_chip), interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -142,19 +134,15 @@ def _lowered(one_chip, kind, dtype, *, use_kernel=False):
                       for d in plan.data)
     program = FigaroEngine()._make_jitted(kind, False, None, None, None)
     return program.lower(
-        plan_spec, data_spec, dtype=np.dtype(dtype), method="tsqr",
-        leaf_rows=256, panel=32, use_kernel=use_kernel, assembly="padded")
+        plan_spec, data_spec, dtype=np.dtype(dtype), leaf_rows=256,
+        use_kernel=use_kernel, assembly="padded")
 
 
-@pytest.mark.parametrize("kernel", ["node_fused", "panel_qr"])
+@pytest.mark.parametrize("kernel", ["node_fused"])
 def test_compiled_kernel_refuses_float64(kernel):
     """float64 on a compiled kernel path fails with a ValueError naming the
     switch and the dtype, before anything is lowered."""
-    if kernel == "node_fused":
-        col = np.ones((64, 1))
-        call = lambda: node_fused_kernel(np.ones((64, 2)), col, col, col,
-                                         col, col, col, interpret=False)
-    else:
-        call = lambda: panel_qr_kernel(np.ones((64, 8)), interpret=False)
+    col = np.ones((64, 1))
     with pytest.raises(ValueError, match=r"use_kernel.*float64"):
-        call()
+        node_fused_kernel(np.ones((64, 2)), col, col, col, col, col, col,
+                          interpret=False)

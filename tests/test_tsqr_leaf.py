@@ -87,17 +87,17 @@ def test_tsqr_on_the_kernel_of_zero_and_repeated_columns(rng, m, n):
                                      interpret=True)).any()
 
 
-def _uses_kernel(dtype, backend, monkeypatch, use_kernel=False):
+def _uses_kernel(dtype, backend, monkeypatch, n=6):
     monkeypatch.setattr(_platform, "backend", lambda: backend)
-    r0 = jnp.zeros((1000, 6), dtype)
-    jaxpr = jax.make_jaxpr(lambda x: postprocess_r0(
-        x, use_kernel=use_kernel))(r0)
+    r0 = jnp.zeros((1000, n), dtype)
+    jaxpr = jax.make_jaxpr(postprocess_r0)(r0)
     return "tsqr_leaf" in str(jaxpr)
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_float32_on_a_tpu_takes_the_kernel(monkeypatch, use_kernel):
-    assert _uses_kernel(jnp.float32, "tpu", monkeypatch, use_kernel)
+# The widths of the benchmark's cells: favorita_sales and yelp_reviews.
+@pytest.mark.parametrize("n", [8, 29])
+def test_float32_on_a_tpu_takes_the_kernel(monkeypatch, n):
+    assert _uses_kernel(jnp.float32, "tpu", monkeypatch, n)
 
 
 @pytest.mark.parametrize("dtype, backend", [
